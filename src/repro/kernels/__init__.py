@@ -14,13 +14,16 @@ are the beyond-paper inference-efficiency levers:
   * ``paged_attention`` — flash-decoding over the serving engine's block
                           arena, block table chased in-kernel (DESIGN.md §10).
 
-Every ``ops.py`` wrapper dispatches: real ``pallas_call`` on TPU,
-``interpret=True`` execution in tests, and a pure-jnp reference path for
-large CPU/dry-run work where interpret-mode would be needlessly slow.
+Every ``ops.py`` wrapper dispatches: real ``pallas_call`` on TPU, and a
+pure-jnp reference path elsewhere, where interpret mode would be needlessly
+slow.  Every Pallas entry point compiles for the chip unless its caller
+passes ``interpret=True`` (tests, and a path forced to the kernel off-TPU).
 """
 import jax
 
 
 def default_interpret() -> bool:
-    """True when no TPU is present (CPU container -> interpret mode)."""
+    """Whether a dispatcher's kernel path runs through the Pallas
+    interpreter: only off-TPU, where forcing the kernel path is the only
+    way to reach it."""
     return jax.default_backend() != "tpu"

@@ -68,7 +68,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int | None = None,
                            bq: int = 128, bk: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """q (B,S,H,hd), k/v (B,S,KV,hd), H = KV*G -> (B,S,H,hd)."""
     b, sq, h, hd = q.shape
     sk, kv = k.shape[1], k.shape[2]

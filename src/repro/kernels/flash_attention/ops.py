@@ -3,6 +3,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.kernels import default_interpret
+
 from .flash import flash_attention_pallas
 from .ref import attention_ref
 
@@ -21,5 +23,5 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None):
         path = "pallas" if jax.default_backend() == "tpu" else "ref"
     if path == "pallas":
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      interpret=jax.default_backend() != "tpu")
+                                      interpret=default_interpret())
     return attention_ref(q, k, v, causal=causal, window=window)

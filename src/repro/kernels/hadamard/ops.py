@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import hadamard as hcore
+from repro.kernels import default_interpret
 from .hadamard import rht_pallas
 
 _FORCE_PATH: str | None = None
@@ -21,7 +22,7 @@ def _rht_block(x2: jax.Array, signs: jax.Array) -> jax.Array:
     if path is None:
         path = "pallas" if jax.default_backend() == "tpu" else "ref"
     if path == "pallas":
-        return rht_pallas(x2, signs, interpret=jax.default_backend() != "tpu")
+        return rht_pallas(x2, signs, interpret=default_interpret())
     return hcore.rht(x2, signs, axis=-1)
 
 

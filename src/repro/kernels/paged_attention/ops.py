@@ -30,6 +30,8 @@ import contextvars
 
 import jax
 
+from repro.kernels import default_interpret
+
 from .paged import paged_attention_pallas
 from .ref import paged_attention_ref
 
@@ -78,11 +80,11 @@ def _resolve() -> str:
 def paged_attention(q, k_arena, v_arena, block_table, pos, ring_cap, *,
                     window: int | None = None):
     """q (B, W, H, hd) at absolute positions pos-W..pos-1 (K/V already in
-    the arena); arenas (N, bs, KV, hd); block_table (B, MB); pos/ring_cap
+    the arena); arenas (N, KV, bs, hd); block_table (B, MB); pos/ring_cap
     (B,) -> (B, W, H, hd)."""
     if _resolve() == "pallas":
         return paged_attention_pallas(
             q, k_arena, v_arena, block_table, pos, ring_cap, window=window,
-            interpret=jax.default_backend() != "tpu")
+            interpret=default_interpret())
     return paged_attention_ref(q, k_arena, v_arena, block_table, pos,
                                ring_cap, window=window)

@@ -8,6 +8,9 @@ scalar-prefetch operand, so the K/V ``BlockSpec`` index maps chase it —
 grid step ``(b, h, j)`` DMAs physical block ``block_table[b, j]`` of KV
 head ``h`` straight from the arena into VMEM, and the ``(bq=W*G, bs)``
 score tile, online-softmax stats, and output accumulator never leave VMEM.
+The arena is laid out ``(N, KV, bs, hd)`` so that one block of one head is
+a ``(bs, hd)`` tile in the last two dimensions, which is what a TPU block
+copy addresses.
 
 Layout choices (mirroring ``kernels/flash_attention/flash.py``):
 
@@ -31,8 +34,9 @@ Layout choices (mirroring ``kernels/flash_attention/flash.py``):
     the ``W*G`` query slab are ordered w-major, so row ``r`` is query
     position ``cnt - W + r // G``.
 
-``interpret=True`` runs the identical kernel through the Pallas
-interpreter so CPU CI exercises the real kernel semantics.
+``interpret=True`` (asked for by tests and forced paths only) runs the
+identical kernel through the Pallas interpreter, so CPU CI exercises the
+real kernel semantics.
 """
 from __future__ import annotations
 
@@ -99,13 +103,13 @@ def paged_attention_pallas(q: jax.Array, k_arena: jax.Array,
                            v_arena: jax.Array, block_table: jax.Array,
                            pos: jax.Array, ring_cap: jax.Array, *,
                            window: int | None = None,
-                           interpret: bool = True) -> jax.Array:
-    """q (B, W, H, hd); arenas (N, bs, KV, hd); block_table (B, MB);
+                           interpret: bool = False) -> jax.Array:
+    """q (B, W, H, hd); arenas (N, KV, bs, hd); block_table (B, MB);
     pos (B,) tokens inserted including the last query (queries sit at
     absolute positions pos-W .. pos-1, and their K/V must already be in the
     arena); ring_cap (B,) per-request ring capacity -> (B, W, H, hd)."""
     b, w, h, hd = q.shape
-    _, bs, kv, _ = k_arena.shape
+    _, kv, bs, _ = k_arena.shape
     g = h // kv
     mb = block_table.shape[1]
     scale = hd ** -0.5
@@ -123,15 +127,15 @@ def paged_attention_pallas(q: jax.Array, k_arena: jax.Array,
     def kv_index(ib, ih, j, bt, c, r, nb):
         # clamp skipped steps to the last live block: the revisited index
         # elides the DMA, and pl.when skips the compute
-        return (bt[ib, jnp.minimum(j, nb[ib] - 1)], 0, ih, 0)
+        return (bt[ib, jnp.minimum(j, nb[ib] - 1)], ih, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b, kv, mb),
         in_specs=[
             pl.BlockSpec((None, None, w * g, hd), q_index),
-            pl.BlockSpec((None, bs, None, hd), kv_index),
-            pl.BlockSpec((None, bs, None, hd), kv_index),
+            pl.BlockSpec((None, None, bs, hd), kv_index),
+            pl.BlockSpec((None, None, bs, hd), kv_index),
         ],
         out_specs=pl.BlockSpec((None, None, w * g, hd), q_index),
         scratch_shapes=[
@@ -145,7 +149,7 @@ def paged_attention_pallas(q: jax.Array, k_arena: jax.Array,
                           scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, w * g, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_attention",
     )(block_table.astype(jnp.int32), cnt, ring, nblk, qr, k_arena, v_arena)
     out = out.reshape(b, kv, w, g, hd)
     return jnp.moveaxis(out, 2, 1).reshape(b, w, h, hd)

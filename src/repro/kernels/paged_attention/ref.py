@@ -24,7 +24,7 @@ def paged_attention_ref(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
                         ring_cap: jax.Array, *,
                         window: int | None = None) -> jax.Array:
     """Same contract as ``paged_attention_pallas``: q (B, W, H, hd), arenas
-    (N, bs, KV, hd), block_table (B, MB), pos (B,) tokens inserted including
+    (N, KV, bs, hd), block_table (B, MB), pos (B,) tokens inserted including
     the last query, ring_cap (B,) -> (B, W, H, hd)."""
     b, w, h, hd = q.shape
     k = paged_gather_kv(k_arena, block_table)       # (B, L, KV, hd)

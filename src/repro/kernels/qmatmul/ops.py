@@ -3,10 +3,11 @@ decode hot path (QuantizedLinear / QuantizedGrouped -> serve/decode) routes
 through.
 
 Paths:
-  * TPU          -> real pallas_call (compiled kernel),
-  * tests        -> pallas_call(interpret=True) (bit-exact kernel semantics),
-  * CPU / dryrun -> pure-jnp reference (same math; interpret-mode would be
-                    pointlessly slow inside a 512-way SPMD dry-run compile).
+  * TPU           -> real pallas_call (compiled kernel),
+  * forced pallas -> pallas_call(interpret=True) off-TPU (bit-exact kernel
+                     semantics for tests),
+  * CPU / dryrun  -> pure-jnp reference (same math; interpret-mode would be
+                     pointlessly slow inside a 512-way SPMD dry-run compile).
 
 Fusion: by default the practical RHT (Alg. 5) is applied *inside* the qmatmul
 kernel (``rht_quantized_matmul``) so rotated activations never round-trip
@@ -32,6 +33,8 @@ import contextvars
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import default_interpret
 
 from .qmatmul import quantized_matmul_pallas, rht_quantized_matmul_pallas
 from .ref import quantized_matmul_ref, rht_quantized_matmul_ref
@@ -72,7 +75,8 @@ def fused_enabled() -> bool:
     return _FUSE_RHT.get()
 
 
-def _resolve_path() -> str:
+def resolve_path() -> str:
+    """"pallas" or "ref": the path a call traced now takes."""
     path = _FORCE_PATH
     if path is None:
         path = "pallas" if jax.default_backend() == "tpu" else "ref"
@@ -84,9 +88,9 @@ def quantized_matmul(x: jax.Array, packed: jax.Array, rescale: jax.Array,
     """Estimate X @ (r * (codes - c_b)) for X (..., d) -> (..., c)."""
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
-    if _resolve_path() == "pallas":
+    if resolve_path() == "pallas":
         y = quantized_matmul_pallas(x2, packed, rescale, bits=bits, d=d,
-                                    interpret=jax.default_backend() != "tpu")
+                                    interpret=default_interpret())
     else:
         y = quantized_matmul_ref(x2, packed, rescale, bits=bits, d=d)
     return y.reshape(*lead, y.shape[-1])
@@ -108,10 +112,10 @@ def rht_quantized_matmul(x: jax.Array, packed: jax.Array, rescale: jax.Array,
         xr = hops.practical_rht(x2.astype(jnp.float32), signs1, signs2)
         return quantized_matmul(xr, packed, rescale, bits=bits, d=d
                                 ).reshape(*lead, -1)
-    if _resolve_path() == "pallas":
+    if resolve_path() == "pallas":
         y = rht_quantized_matmul_pallas(
             x2, packed, rescale, signs1, signs2, bits=bits, d=d,
-            interpret=jax.default_backend() != "tpu")
+            interpret=default_interpret())
     else:
         y = rht_quantized_matmul_ref(x2, packed, rescale, signs1, signs2,
                                      bits=bits, d=d)

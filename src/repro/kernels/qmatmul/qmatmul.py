@@ -3,16 +3,20 @@
     Y = (X @ (codes - c_b)) * r
       = (X @ codes) * r - c_b * rowsum(X) * r
 
-Codes arrive packed (8 // bits codes per uint8, packed along the contraction
-axis d) and are unpacked *inside* the kernel, so HBM->VMEM traffic for the
-weights is b/16 of the bf16 baseline — that is the entire point of weight-only
-PTQ at decode time and the term the paper's technique moves (§Roofline).
+Codes arrive packed (``core/packing.py``: 8 // bits codes per uint8, packed
+along the contraction axis d in PACK_ROWS-row tiles with one contiguous row
+band per bit plane) and are unpacked *inside* the kernel, so HBM->VMEM
+traffic for the weights is b/16 of the bf16 baseline — that is the entire
+point of weight-only PTQ at decode time and the term the paper's technique
+moves (§Roofline).
 
-Blocking: grid (n/bn, c/bc, d/bk), k innermost so the (bn, bc) f32 accumulator
-and the (bn, 1) rowsum scratch live in VMEM across the k sweep; the rescale /
-z-correction epilogue fires on the last k step.  MXU dims (bn, bk, bc) are
-multiples of 128 by construction; the uint8 unpack is a VPU shift/mask on a
-(bk//per, bc) tile broadcast to (bk, bc).
+Blocking: grid (n/bn, c/bc, d/bk), k innermost so the (bn, bc) f32
+accumulator and the (bn, 1) rowsum scratch live in VMEM across the k sweep;
+the rescale / z-correction epilogue fires on the last k step.  Every
+in-kernel step keeps the TPU's (8, 128) vector layout: the packed uint8
+tile widens to int32 before the shifts (8-bit shifts do not lower), each
+bit plane unpacks to a contiguous row band so the planes simply stack, and
+codes reach f32 through int32.
 """
 from __future__ import annotations
 
@@ -23,7 +27,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.hadamard import _split_dim, hadamard_matrix, largest_pow2_leq
+from repro.core.hadamard import largest_pow2_leq
+from repro.core.packing import PACK_ROWS, codes_per_byte
+from repro.kernels.hadamard.hadamard import chunk_hadamard, hadamard_rows
 
 DEFAULT_BN = 128
 DEFAULT_BC = 128
@@ -31,13 +37,49 @@ DEFAULT_BK = 512
 
 
 def _unpack_tile(packed: jax.Array, bits: int) -> jax.Array:
-    """(bk//per, bc) uint8 -> (bk, bc) uint8 via VPU shift/mask."""
-    per = 8 // bits if bits in (1, 2, 4, 8) else 1
+    """(bk // per, bc) packed uint8 -> (bk, bc) f32 codes."""
+    words = packed.astype(jnp.int32)
+    per = codes_per_byte(bits)
     if per == 1:
-        return packed
-    mask = jnp.uint8((1 << bits) - 1)
-    parts = [((packed >> jnp.uint8(s * bits)) & mask) for s in range(per)]
-    return jnp.stack(parts, axis=1).reshape(-1, packed.shape[-1])
+        return words.astype(jnp.float32)
+    band = PACK_ROWS // per
+    mask = (1 << bits) - 1
+    planes = [(words[t:t + band] >> (s * bits)) & mask
+              for t in range(0, words.shape[0], band) for s in range(per)]
+    return jnp.concatenate(planes, axis=0).astype(jnp.float32)
+
+
+def _accumulate(x, packed_ref, acc_ref, zacc_ref, *, bits: int,
+                compute_dtype):
+    x = x.astype(compute_dtype)                                   # (bn, bk)
+    codes = _unpack_tile(packed_ref[...], bits).astype(compute_dtype)
+    acc_ref[...] += jnp.dot(x, codes, preferred_element_type=jnp.float32)
+    zacc_ref[...] += jnp.sum(x.astype(jnp.float32), axis=1, keepdims=True)
+
+
+def _epilogue(acc_ref, zacc_ref, rescale_ref, out_ref, *, bits: int):
+    c_b = ((1 << bits) - 1) / 2.0
+    out_ref[...] = ((acc_ref[...] - c_b * zacc_ref[...]) * rescale_ref[...]
+                    ).astype(out_ref.dtype)
+
+
+def _tiling(n: int, d: int, c: int, bits: int, bn: int, bc: int, bk: int):
+    """(bn, n_pad, c_pad, d_pad, packed rows) for an (n, d) x (d, c) call.
+    The row block shrinks to the batch (a multiple of 8 sublanes) so a
+    decode step does not pay for 128 rows."""
+    per = codes_per_byte(bits)
+    assert bk % 128 == 0 and (per == 1 or bk % PACK_ROWS == 0)
+    bn = min(bn, pl.cdiv(n, 8) * 8)
+    d_pad = pl.cdiv(d, bk) * bk
+    return (bn, pl.cdiv(n, bn) * bn, pl.cdiv(c, bc) * bc, d_pad,
+            d_pad // per)
+
+
+def _pad(a: jax.Array, shape) -> jax.Array:
+    """Zero-pad ``a`` up to ``shape`` (no copy when it already fits)."""
+    if a.shape == tuple(shape):
+        return a
+    return jnp.pad(a, [(0, t - s) for s, t in zip(a.shape, shape)])
 
 
 def _kernel(x_ref, packed_ref, rescale_ref, out_ref, acc_ref, zacc_ref,
@@ -49,16 +91,12 @@ def _kernel(x_ref, packed_ref, rescale_ref, out_ref, acc_ref, zacc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         zacc_ref[...] = jnp.zeros_like(zacc_ref)
 
-    x = x_ref[...].astype(compute_dtype)                     # (bn, bk)
-    codes = _unpack_tile(packed_ref[...], bits).astype(compute_dtype)  # (bk, bc)
-    acc_ref[...] += jnp.dot(x, codes, preferred_element_type=jnp.float32)
-    zacc_ref[...] += jnp.sum(x.astype(jnp.float32), axis=1, keepdims=True)
+    _accumulate(x_ref[...], packed_ref, acc_ref, zacc_ref, bits=bits,
+                compute_dtype=compute_dtype)
 
     @pl.when(k == n_k - 1)
-    def _epilogue():
-        c_b = ((1 << bits) - 1) / 2.0
-        r = rescale_ref[...].astype(jnp.float32)             # (1, bc)
-        out_ref[...] = ((acc_ref[...] - c_b * zacc_ref[...]) * r).astype(out_ref.dtype)
+    def _fin():
+        _epilogue(acc_ref, zacc_ref, rescale_ref, out_ref, bits=bits)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "d", "bn", "bc", "bk",
@@ -66,25 +104,18 @@ def _kernel(x_ref, packed_ref, rescale_ref, out_ref, acc_ref, zacc_ref,
 def quantized_matmul_pallas(x: jax.Array, packed: jax.Array, rescale: jax.Array,
                             *, bits: int, d: int,
                             bn: int = DEFAULT_BN, bc: int = DEFAULT_BC,
-                            bk: int = DEFAULT_BK, interpret: bool = True,
+                            bk: int = DEFAULT_BK, interpret: bool = False,
                             compute_dtype=jnp.float32) -> jax.Array:
     """x (n, d) f32/bf16, packed (packed_rows, c) uint8, rescale (c,) -> (n, c)."""
     n, _ = x.shape
     c = packed.shape[1]
-    per = 8 // bits if bits in (1, 2, 4, 8) else 1
-    assert bk % per == 0 and bk % 128 == 0
-    d_pad = pl.cdiv(d, bk) * bk
-    n_pad = pl.cdiv(n, bn) * bn
-    c_pad = pl.cdiv(c, bc) * bc
-    xp = jnp.zeros((n_pad, d_pad), x.dtype).at[:n, :d].set(x)
-    pp = jnp.zeros((d_pad // per, c_pad), jnp.uint8)
-    pp = pp.at[: packed.shape[0], :c].set(packed)
-    rp = jnp.zeros((1, c_pad), rescale.dtype).at[0, :c].set(rescale)
+    bn, n_pad, c_pad, d_pad, p_rows = _tiling(n, d, c, bits, bn, bc, bk)
+    per = codes_per_byte(bits)
     n_k = d_pad // bk
-    grid = (n_pad // bn, c_pad // bc, n_k)
     out = pl.pallas_call(
-        functools.partial(_kernel, bits=bits, n_k=n_k, compute_dtype=compute_dtype),
-        grid=grid,
+        functools.partial(_kernel, bits=bits, n_k=n_k,
+                          compute_dtype=compute_dtype),
+        grid=(n_pad // bn, c_pad // bc, n_k),
         in_specs=[
             pl.BlockSpec((bn, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // per, bc), lambda i, j, k: (k, j)),
@@ -96,67 +127,63 @@ def quantized_matmul_pallas(x: jax.Array, packed: jax.Array, rescale: jax.Array,
             pltpu.VMEM((bn, bc), jnp.float32),   # f32 accumulator
             pltpu.VMEM((bn, 1), jnp.float32),    # rowsum(X) for the z term
         ],
-        interpret=interpret,
-    )(xp, pp, rp)
+        interpret=interpret, name="qmatmul",
+    )(_pad(x, (n_pad, d_pad)), _pad(packed, (p_rows, c_pad)),
+      _pad(rescale.astype(jnp.float32)[None], (1, c_pad)))
     return out[:n, :c]
 
 
 # ===================================================== fused RHT + qmatmul
 
 
-def _rht_rows(x, signs, h1, h2, *, d1: int, d2: int):
-    """H_{d1*d2} (D x) for a VMEM row tile x (bn, d1*d2); signs (1, d1*d2).
+def _practical_rht_rows(x, signs1, signs2, h, *, d: int, d_hat: int):
+    """Alg. 5 on every row of a (bn, d_pad) tile whose lanes >= d are zero.
 
-    Same Kronecker two-matmul factorization as kernels/hadamard, inlined so
-    the rotated tile never leaves VMEM before the quantized GEMM consumes it.
-    """
-    x = x * signs
-    bn = x.shape[0]
-    xr = x.reshape(bn * d1, d2)
-    xr = jnp.dot(xr, h2, preferred_element_type=jnp.float32)           # H_{d2}
-    xr = xr.reshape(bn, d1, d2).swapaxes(1, 2).reshape(bn * d2, d1)
-    xr = jnp.dot(xr, h1, preferred_element_type=jnp.float32)           # H_{d1}
-    return xr.reshape(bn, d2, d1).swapaxes(1, 2).reshape(bn, d1 * d2)
+    The second (overlapped) block starts at the unaligned lane d - d_hat, so
+    it is brought to lane 0 with a lane rotation, transformed, and rotated
+    back — an unaligned lane slice does not lower."""
+    d_pad = x.shape[1]
+    row = hadamard_rows(x[:, :d_hat] * signs1, h)
+    if d_pad > d_hat:
+        row = jnp.concatenate([row, x[:, d_hat:]], axis=1)
+    if d != d_hat:
+        lo = d - d_hat
+        r = pltpu.roll(row, d_pad - lo, 1)           # lane lo -> lane 0
+        r = jnp.concatenate([hadamard_rows(r[:, :d_hat] * signs2, h),
+                             r[:, d_hat:]], axis=1)
+        row = pltpu.roll(r, lo, 1)
+    return row
 
 
-def _fused_kernel(x_ref, signs1_ref, signs2_ref, h1_ref, h2_ref, packed_ref,
+def _fused_kernel(x_ref, signs1_ref, signs2_ref, h_ref, packed_ref,
                   rescale_ref, out_ref, xrot_ref, acc_ref, zacc_ref,
                   *, bits: int, n_k: int, bk: int, d: int, d_hat: int,
-                  d1: int, d2: int, overlapped: bool, compute_dtype):
+                  compute_dtype):
     j, k = pl.program_id(1), pl.program_id(2)
 
-    # Rotate once per row block (first (j, k) visit); the (bn, d_pad) result
-    # stays resident in VMEM scratch for the whole (j, k) sweep — rotated
-    # activations never touch HBM (Alg. 3 fused with Alg. 5).
+    # Rotate once per row block (first (j, k) visit); the result stays
+    # resident in VMEM scratch, one (bn, bk) slab per k, for the whole
+    # (j, k) sweep — rotated activations never touch HBM (Alg. 3 fused
+    # with Alg. 5).
     @pl.when((j == 0) & (k == 0))
     def _rotate():
-        xf = x_ref[...].astype(jnp.float32)                  # (bn, d_pad)
-        blk1 = _rht_rows(xf[:, :d_hat], signs1_ref[...], h1_ref[...],
-                         h2_ref[...], d1=d1, d2=d2)
-        row = (jnp.concatenate([blk1, xf[:, d_hat:]], axis=1)
-               if xf.shape[1] > d_hat else blk1)
-        if overlapped:                                       # Alg. 5, d not pow2
-            lo = d - d_hat
-            blk2 = _rht_rows(row[:, lo:d], signs2_ref[...], h1_ref[...],
-                             h2_ref[...], d1=d1, d2=d2)
-            row = jnp.concatenate([row[:, :lo], blk2, row[:, d:]], axis=1)
-        xrot_ref[...] = row
+        row = _practical_rht_rows(
+            x_ref[...].astype(jnp.float32), signs1_ref[...],
+            signs2_ref[...], h_ref[...], d=d, d_hat=d_hat)
+        for t in range(n_k):
+            xrot_ref[t] = row[:, t * bk:(t + 1) * bk]
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         zacc_ref[...] = jnp.zeros_like(zacc_ref)
 
-    x = xrot_ref[:, pl.ds(k * bk, bk)].astype(compute_dtype)            # (bn, bk)
-    codes = _unpack_tile(packed_ref[...], bits).astype(compute_dtype)   # (bk, bc)
-    acc_ref[...] += jnp.dot(x, codes, preferred_element_type=jnp.float32)
-    zacc_ref[...] += jnp.sum(x.astype(jnp.float32), axis=1, keepdims=True)
+    _accumulate(xrot_ref[k], packed_ref, acc_ref, zacc_ref, bits=bits,
+                compute_dtype=compute_dtype)
 
     @pl.when(k == n_k - 1)
-    def _epilogue():
-        c_b = ((1 << bits) - 1) / 2.0
-        r = rescale_ref[...].astype(jnp.float32)             # (1, bc)
-        out_ref[...] = ((acc_ref[...] - c_b * zacc_ref[...]) * r).astype(out_ref.dtype)
+    def _fin():
+        _epilogue(acc_ref, zacc_ref, rescale_ref, out_ref, bits=bits)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "d", "bn", "bc", "bk",
@@ -165,7 +192,7 @@ def rht_quantized_matmul_pallas(x: jax.Array, packed: jax.Array,
                                 rescale: jax.Array, signs1: jax.Array,
                                 signs2: jax.Array | None, *, bits: int, d: int,
                                 bn: int = DEFAULT_BN, bc: int = DEFAULT_BC,
-                                bk: int = DEFAULT_BK, interpret: bool = True,
+                                bk: int = DEFAULT_BK, interpret: bool = False,
                                 compute_dtype=jnp.float32) -> jax.Array:
     """Y = practical_rht(x) @ (r * (codes - c_b)) without the HBM round trip.
 
@@ -175,48 +202,38 @@ def rht_quantized_matmul_pallas(x: jax.Array, packed: jax.Array,
     n, _ = x.shape
     c = packed.shape[1]
     d_hat = largest_pow2_leq(d)
-    d1, d2 = _split_dim(d_hat)
-    overlapped = d_hat != d
-    if overlapped and signs2 is None:
+    if d_hat != d and signs2 is None:
         raise ValueError("signs2 required when d is not a power of 2")
     if signs2 is None:
         signs2 = jnp.zeros((d_hat,), jnp.float32)            # dead input
-    per = 8 // bits if bits in (1, 2, 4, 8) else 1
-    assert bk % per == 0 and bk % 128 == 0
-    d_pad = pl.cdiv(d, bk) * bk
-    n_pad = pl.cdiv(n, bn) * bn
-    c_pad = pl.cdiv(c, bc) * bc
-    xp = jnp.zeros((n_pad, d_pad), x.dtype).at[:n, :d].set(x)
-    pp = jnp.zeros((d_pad // per, c_pad), jnp.uint8)
-    pp = pp.at[: packed.shape[0], :c].set(packed)
-    rp = jnp.zeros((1, c_pad), rescale.dtype).at[0, :c].set(rescale)
-    h1 = hadamard_matrix(d1)
-    h2 = hadamard_matrix(d2)
+    bn, n_pad, c_pad, d_pad, p_rows = _tiling(n, d, c, bits, bn, bc, bk)
+    per = codes_per_byte(bits)
+    h = chunk_hadamard(d_hat)
     n_k = d_pad // bk
-    grid = (n_pad // bn, c_pad // bc, n_k)
     out = pl.pallas_call(
         functools.partial(_fused_kernel, bits=bits, n_k=n_k, bk=bk, d=d,
-                          d_hat=d_hat, d1=d1, d2=d2, overlapped=overlapped,
-                          compute_dtype=compute_dtype),
-        grid=grid,
+                          d_hat=d_hat, compute_dtype=compute_dtype),
+        grid=(n_pad // bn, c_pad // bc, n_k),
         in_specs=[
             # same block for every (j, k) -> fetched from HBM once per row block
             pl.BlockSpec((bn, d_pad), lambda i, j, k: (i, 0)),
             pl.BlockSpec((1, d_hat), lambda i, j, k: (0, 0)),
             pl.BlockSpec((1, d_hat), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((d1, d1), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((d2, d2), lambda i, j, k: (0, 0)),
+            pl.BlockSpec(h.shape, lambda i, j, k: (0, 0)),
             pl.BlockSpec((bk // per, bc), lambda i, j, k: (k, j)),
             pl.BlockSpec((1, bc), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bn, bc), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_pad, c_pad), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((bn, d_pad), jnp.float32),  # rotated activations
-            pltpu.VMEM((bn, bc), jnp.float32),     # f32 accumulator
-            pltpu.VMEM((bn, 1), jnp.float32),      # rowsum for the z term
+            pltpu.VMEM((n_k, bn, bk), jnp.float32),  # rotated activations
+            pltpu.VMEM((bn, bc), jnp.float32),       # f32 accumulator
+            pltpu.VMEM((bn, 1), jnp.float32),        # rowsum for the z term
         ],
-        interpret=interpret,
-    )(xp, signs1.reshape(1, d_hat).astype(jnp.float32),
-      signs2.reshape(1, d_hat).astype(jnp.float32), h1, h2, pp, rp)
+        interpret=interpret, name="rht_qmatmul",
+    )(_pad(x, (n_pad, d_pad)),
+      signs1.reshape(1, d_hat).astype(jnp.float32),
+      signs2.reshape(1, d_hat).astype(jnp.float32), h,
+      _pad(packed, (p_rows, c_pad)),
+      _pad(rescale.astype(jnp.float32)[None], (1, c_pad)))
     return out[:n, :c]

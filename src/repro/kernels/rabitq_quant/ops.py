@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 
 from repro.core.rabitq import RabitqCodes
+from repro.kernels import default_interpret
 from .quantize import quantize_pallas
 from .ref import quantize_ref
 
@@ -23,7 +24,7 @@ def quantize(w: jax.Array, bits: int, n_candidates: int = 12) -> RabitqCodes:
     if path == "pallas":
         codes, rescale = quantize_pallas(
             w, bits=bits, n_candidates=n_candidates,
-            interpret=jax.default_backend() != "tpu")
+            interpret=default_interpret())
     else:
         codes, rescale = quantize_ref(w, bits, n_candidates)
     return RabitqCodes(codes=codes, rescale=rescale, bits=bits)

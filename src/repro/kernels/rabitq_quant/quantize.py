@@ -48,7 +48,7 @@ def _kernel(w_ref, scales_ref, codes_ref, rescale_ref, *, bits: int, n_cand: int
                                              "interpret"))
 def quantize_pallas(w: jax.Array, *, bits: int, n_candidates: int = 12,
                     lo: float = 0.3, hi: float = 1.05, bc: int | None = None,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """Quantize columns of w (d, c): returns (codes uint8 (d, c), rescale (c,))."""
     d, c = w.shape
     if bc is None:

@@ -7,12 +7,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where the installed JAX
-    has them (>= 0.5); older releases only have Auto semantics anyway."""
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(at.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

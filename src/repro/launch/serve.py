@@ -60,6 +60,7 @@ from repro.core import pipeline as pipe
 from repro.data import ByteTokenizer
 from repro.kernels.paged_attention import ops as pops
 from repro.kernels.qmatmul import ops as qops
+from repro.launch.jaxcache import use_compile_cache
 from repro.models import decode as decmod
 from repro.models import transformer as tf
 from repro.serve import PagedServer, PoolConfig, Request
@@ -104,7 +105,7 @@ class BatchedServer:
         return np.stack([np.asarray(t) for t in out], axis=1)
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama2-7b")
     ap.add_argument("--tiny", action="store_true")
@@ -167,19 +168,24 @@ def main():
                     help="front door: max fraction of engine slots one "
                          "tenant may hold while other tenants wait "
                          "(default 1.0 = uncapped)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.speculate and args.lockstep:
         ap.error("--speculate needs the paged engine (drop --lockstep)")
     if args.tp > 1 and args.lockstep:
         ap.error("--tp needs the paged engine (drop --lockstep)")
     if args.serve and args.lockstep:
         ap.error("--serve needs the paged engine (drop --lockstep)")
+    return args
 
+
+def load_model(args, seed: int = 0):
+    """Random-init the ``--arch`` model from ``seed`` and, with
+    ``--avg-bits`` / ``--speculate``, calibrate and RaanA-quantize it.
+    Returns ``(cfg, params, draft_params, report)``; ``report`` is the
+    target's ``QuantReport`` (None for an fp32 target)."""
     cfg = get_tiny(args.arch) if args.tiny else get_config(args.arch)
-    key = jax.random.PRNGKey(0)
-    params = tf.init_params(cfg, key)
-
-    draft_params = None
+    params = tf.init_params(cfg, jax.random.PRNGKey(seed))
+    draft_params, rep = None, None
     if args.avg_bits or args.speculate:
         stats_msg = (f"{args.avg_bits} avg bits" if args.avg_bits
                      else "fp32 target")
@@ -190,24 +196,48 @@ def main():
         stats = cal.calibrate(
             lambda p, b, ctx: tf.loss_fn(cfg, p, b, ctx=ctx, scan=False),
             params, [{"tokens": jnp.asarray(toks)}])
+        qkey = jax.random.PRNGKey(seed + 1)
         if args.avg_bits and args.speculate:
             params, rep, draft_params, drep = pipe.quantize_model_dual(
-                cfg, params, stats, args.avg_bits, args.draft_bits,
-                jax.random.PRNGKey(1))
+                cfg, params, stats, args.avg_bits, args.draft_bits, qkey)
             print(f"quantized {rep.n_layers} layers, achieved "
                   f"{rep.avg_bits:.3f} target / {drep.avg_bits:.3f} draft "
                   f"bits in {rep.wall_time_s + drep.wall_time_s:.1f}s")
         elif args.avg_bits:
             params, rep = pipe.quantize_model(cfg, params, stats,
-                                              args.avg_bits,
-                                              jax.random.PRNGKey(1))
+                                              args.avg_bits, qkey)
             print(f"quantized {rep.n_layers} layers, achieved "
                   f"{rep.avg_bits:.3f} bits in {rep.wall_time_s:.1f}s")
         else:   # fp32 target, quantized draft
             draft_params, drep = pipe.quantize_model(
-                cfg, params, stats, args.draft_bits, jax.random.PRNGKey(1))
+                cfg, params, stats, args.draft_bits, qkey)
             print(f"quantized draft: {drep.avg_bits:.3f} bits in "
                   f"{drep.wall_time_s:.1f}s")
+    return cfg, params, draft_params, rep
+
+
+def build_engine(args, cfg, params, draft_params=None, mesh=None):
+    """The paged engine the CLI serves with (``mesh`` overrides the
+    ``--tp`` host mesh)."""
+    pool = PoolConfig(max_slots=args.slots, block_size=args.block_size,
+                      max_context=args.prompt_len + args.gen,
+                      prefill_chunk=args.prefill_chunk,
+                      prefix_cache=args.prefix_cache,
+                      kv_dtype=(jnp.bfloat16 if args.kv_dtype == "bf16"
+                                else jnp.float32))
+    if mesh is None and args.tp > 1:
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(tp=args.tp)
+    return PagedServer(cfg, params, pool, fused=not args.unfused,
+                       paged_kernel=args.paged_kernel,
+                       draft_params=draft_params,
+                       speculate=args.speculate, mesh=mesh)
+
+
+def main(argv=None):
+    use_compile_cache()
+    args = parse_args(argv)
+    cfg, params, draft_params, _ = load_model(args)
 
     tok = ByteTokenizer(cfg.vocab)
     prompt = tok.encode("the quick brown fox " * 8)[: args.prompt_len]
@@ -221,18 +251,7 @@ def main():
         sample = out[0]
         extra = "lockstep"
     else:
-        pool = PoolConfig(max_slots=args.slots, block_size=args.block_size,
-                          max_context=args.prompt_len + args.gen,
-                          prefill_chunk=args.prefill_chunk,
-                          prefix_cache=args.prefix_cache,
-                          kv_dtype=(jnp.bfloat16 if args.kv_dtype == "bf16"
-                                    else jnp.float32))
-        from repro.launch.mesh import make_host_mesh
-        mesh = make_host_mesh(tp=args.tp) if args.tp > 1 else None
-        engine = PagedServer(cfg, params, pool, fused=not args.unfused,
-                             paged_kernel=args.paged_kernel,
-                             draft_params=draft_params,
-                             speculate=args.speculate, mesh=mesh)
+        engine = build_engine(args, cfg, params, draft_params)
         if args.serve:
             from repro.serve.frontdoor import FrontDoor, SchedConfig
             door = FrontDoor(

@@ -131,7 +131,9 @@ def cache_insert(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
 # ------------------------------------------------------------ paged caches
 #
 # The serving engine (repro/serve) stores K/V in a fixed arena of
-# (num_blocks, block_size, KV, hd) blocks shared by all requests; each request
+# (num_blocks, KV, block_size, hd) blocks shared by all requests (one block
+# of one KV head is a (block_size, hd) tile, the unit the paged-attention
+# kernel copies into VMEM); each request
 # owns a row of a block table mapping logical block -> physical block.
 # Logical token index for a request at absolute position p is p % ring_cap,
 # where ring_cap = allocated_blocks * block_size: full-context requests get
@@ -143,10 +145,10 @@ def cache_insert(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
 
 
 def paged_gather_kv(arena: jax.Array, block_table: jax.Array) -> jax.Array:
-    """arena (N, bs, ...), block_table (B, MB) int32 -> (B, MB*bs, ...)."""
-    g = arena[block_table]                       # (B, MB, bs, ...)
-    b, mb, bs = g.shape[:3]
-    return g.reshape(b, mb * bs, *arena.shape[2:])
+    """arena (N, KV, bs, hd), block_table (B, MB) int32 -> (B, MB*bs, KV, hd)."""
+    g = arena[block_table]                       # (B, MB, KV, bs, hd)
+    b, mb, kv, bs, hd = g.shape
+    return jnp.swapaxes(g, 2, 3).reshape(b, mb * bs, kv, hd)
 
 
 def paged_slot_positions(pos: jax.Array, ring_cap: jax.Array,
@@ -211,13 +213,22 @@ def paged_multi_write_indices(positions: jax.Array, ring_cap: jax.Array,
     return pb, off
 
 
+def paged_write(arena: jax.Array, pb: jax.Array, off: jax.Array,
+                x: jax.Array) -> jax.Array:
+    """Scatter per-token K or V rows ``x`` (*idx, KV, hd) into the arena
+    (N, KV, bs, hd) at physical blocks ``pb`` / in-block offsets ``off``
+    (both shaped ``idx``, from ``paged_write_indices`` or
+    ``paged_multi_write_indices``)."""
+    return arena.at[pb, :, off].set(x.astype(arena.dtype))
+
+
 def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
                            v_arena: jax.Array, block_table: jax.Array,
                            pos: jax.Array, ring_cap: jax.Array, *,
                            window: Optional[int] = None) -> jax.Array:
     """One-token attention over the paged arena.
 
-    q (B,1,H,hd); arenas (N, bs, KV, hd); block_table (B, MB); pos (B,) =
+    q (B,1,H,hd); arenas (N, KV, bs, hd); block_table (B, MB); pos (B,) =
     tokens inserted including the current one (whose K/V must already be in
     the arena); ring_cap (B,) per-request ring capacity.  Equivalent to
     ``decode_attention`` on a dense per-request cache (window masking is

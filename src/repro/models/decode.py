@@ -381,11 +381,11 @@ def layer_decode_paged(cfg: ModelConfig, mixer: str, lp: dict, h: jax.Array,
         if cfg.pos == "mrope":
             posb = jnp.broadcast_to(pos[None, :, None], (3, b, 1)).astype(jnp.int32)
         q, k, v = _attn_qkv(cfg, p, hn, posb)
-        block_size = cache["k"].shape[1]
+        block_size = cache["k"].shape[2]
         pb, off = attnmod.paged_write_indices(pos, ring_cap, block_tables,
                                               block_size, active)
-        k_arena = cache["k"].at[pb, off].set(k[:, 0].astype(cache["k"].dtype))
-        v_arena = cache["v"].at[pb, off].set(v[:, 0].astype(cache["v"].dtype))
+        k_arena = attnmod.paged_write(cache["k"], pb, off, k[:, 0])
+        v_arena = attnmod.paged_write(cache["v"], pb, off, v[:, 0])
         out = attnmod.paged_decode_attention(q, k_arena, v_arena, block_tables,
                                              pos + 1, ring_cap,
                                              window=cfg.window)
@@ -482,12 +482,12 @@ def layer_verify_paged(cfg: ModelConfig, mixer: str, lp: dict, h: jax.Array,
     if cfg.pos == "mrope":
         posb = jnp.broadcast_to(positions[None], (3, b, w)).astype(jnp.int32)
     q, k, v = _attn_qkv(cfg, p, hn, posb)
-    block_size = cache["k"].shape[1]
+    block_size = cache["k"].shape[2]
     pb, off = attnmod.paged_multi_write_indices(positions, ring_cap,
                                                 block_tables, block_size,
                                                 write_mask)
-    new_cache["k"] = cache["k"].at[pb, off].set(k.astype(cache["k"].dtype))
-    new_cache["v"] = cache["v"].at[pb, off].set(v.astype(cache["v"].dtype))
+    new_cache["k"] = attnmod.paged_write(cache["k"], pb, off, k)
+    new_cache["v"] = attnmod.paged_write(cache["v"], pb, off, v)
     if pops.kernel_enabled():
         # kernel path: the span's K/V is committed above, so one flash-decode
         # sweep over the arena covers history + span (causality within the
@@ -592,13 +592,11 @@ def layer_prefill_chunk(cfg: ModelConfig, mixer: str, lp: dict, h: jax.Array,
             window=cfg.window)
         out = tpmod.gather_heads(out, cfg.n_heads)
         mix = linear(p["wo"], out.reshape(b, c, -1))
-        block_size = cache["k"].shape[1]
+        block_size = cache["k"].shape[2]
         pb, off = attnmod.paged_write_indices(chunk_pos, ring_cap, bt_row,
                                               block_size)
-        new_cache["k"] = cache["k"].at[pb, off].set(
-            k[0].astype(cache["k"].dtype))
-        new_cache["v"] = cache["v"].at[pb, off].set(
-            v[0].astype(cache["v"].dtype))
+        new_cache["k"] = attnmod.paged_write(cache["k"], pb, off, k[0])
+        new_cache["v"] = attnmod.paged_write(cache["v"], pb, off, v[0])
     elif mixer == "mla":
         mix, mc = mlamod.mla_prefill_chunk(lp["mla"], hn, cfg.mla,
                                            cache["mla"], pos0, slot)

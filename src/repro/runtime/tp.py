@@ -44,7 +44,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .sharding import _fit
@@ -211,7 +210,7 @@ def prepare_params(cfg, params: Any, mesh: Mesh):
 
 def cache_spec_list(caches: Any, mesh: Mesh, plan: TPPlan) -> list[P]:
     """Specs for the pool cache tree, ordered like its flatten order: the
-    attention block arenas (n_j, N, bs, KV, hd) shard their KV-head axis
+    attention block arenas (n_j, N, KV, bs, hd) shard their KV-head axis
     when the plan shards attention; per-slot recurrent/MLA state and
     everything else replicates (block tables never reach device state —
     they are step *arguments*, replicated like the rest of the scheduler's
@@ -221,7 +220,7 @@ def cache_spec_list(caches: Any, mesh: Mesh, plan: TPPlan) -> list[P]:
     for path, leaf in flat:
         names = _path_names(path)
         if plan.attn and leaf.ndim == 5 and names and names[-1] in ("k", "v"):
-            specs.append(_fit(P(None, None, None, AXIS, None),
+            specs.append(_fit(P(None, None, AXIS, None, None),
                               leaf.shape, mesh))
         else:
             specs.append(P(*([None] * leaf.ndim)))
@@ -249,7 +248,7 @@ def sharded_call(core: Callable, mesh: Mesh, pspecs: list[P],
     metadata that spec-tree prefix matching would trip over).  All step
     arguments and the output are replicated; caches go in and come out
     under the same specs, so jit donation of the pool buffers survives the
-    wrapper.  ``check_rep=False``: the output IS replicated by construction
+    wrapper.  ``check_vma=False``: the output IS replicated by construction
     (every shard finishes with fully-gathered activations) but shard_map
     cannot prove it through ``all_gather``-of-disjoint-slices."""
     psp, csp = tuple(pspecs), tuple(cspecs)
@@ -265,9 +264,9 @@ def sharded_call(core: Callable, mesh: Mesh, pspecs: list[P],
             out, nc = core(p, c, *arrs)
             return out, tuple(jax.tree_util.tree_flatten(nc)[0])
 
-        out, ncl = shard_map(
+        out, ncl = jax.shard_map(
             body, mesh=mesh, in_specs=(psp, csp, _R),
-            out_specs=(_R, csp), check_rep=False)(
+            out_specs=(_R, csp), check_vma=False)(
                 tuple(pl), tuple(cl), tuple(arrays))
         return out, jax.tree_util.tree_unflatten(ctd, list(ncl))
 
@@ -286,8 +285,9 @@ def sharded_cache_op(core: Callable, mesh: Mesh, cspecs: list[P]) -> Callable:
             nc = core(jax.tree_util.tree_unflatten(ctd, cl_), *arrs)
             return tuple(jax.tree_util.tree_flatten(nc)[0])
 
-        ncl = shard_map(body, mesh=mesh, in_specs=(csp, _R), out_specs=csp,
-                        check_rep=False)(tuple(cl), tuple(arrays))
+        ncl = jax.shard_map(body, mesh=mesh, in_specs=(csp, _R),
+                            out_specs=csp, check_vma=False)(tuple(cl),
+                                                            tuple(arrays))
         return jax.tree_util.tree_unflatten(ctd, list(ncl))
 
     return call

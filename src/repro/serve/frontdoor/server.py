@@ -102,7 +102,9 @@ class FrontDoor:
         self._watchers: dict[int, asyncio.Queue] = {}
         self._wake = threading.Event()       # driver: new work submitted
         self._stopping = threading.Event()
+        self.listening = threading.Event()   # set once self.port is bound
         self._loop: asyncio.AbstractEventLoop | None = None
+        self._stop: asyncio.Event | None = None
 
     # -------------------------------------------------------- driver thread
 
@@ -279,10 +281,14 @@ class FrontDoor:
         driver = threading.Thread(target=self._drive, daemon=True,
                                   name="frontdoor-driver")
         driver.start()
-        stop = asyncio.Event()
+        self._stop = stop = asyncio.Event()
+        # signal handlers exist only on the main thread; a server run from
+        # another thread is stopped with shutdown()
         for sig in (signal.SIGINT, signal.SIGTERM):
-            with contextlib.suppress(NotImplementedError, ValueError):
+            with contextlib.suppress(NotImplementedError, ValueError,
+                                     RuntimeError):
                 self._loop.add_signal_handler(sig, stop.set)
+        self.listening.set()
         # a driver crash must also bring the listener down
         async def watch_driver():
             while driver.is_alive() and not self._stopping.is_set():
@@ -300,8 +306,13 @@ class FrontDoor:
             driver.join(timeout=5.0)
             print("frontdoor shut down cleanly", flush=True)
 
+    def shutdown(self) -> None:
+        """Stop a server that runs on another thread, as SIGTERM would."""
+        self._loop.call_soon_threadsafe(self._stop.set)
+
     def serve_forever(self) -> None:
-        """Run until SIGINT/SIGTERM (clean shutdown) or driver crash."""
+        """Run until SIGINT/SIGTERM or ``shutdown()`` (clean shutdown) or
+        driver crash."""
         try:
             asyncio.run(self._main())
         except KeyboardInterrupt:

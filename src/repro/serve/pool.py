@@ -1,13 +1,15 @@
-"""Paged KV-cache pool: a fixed arena of (num_blocks, block_size, KV, hd)
+"""Paged KV-cache pool: a fixed arena of (num_blocks, KV, block_size, hd)
 blocks shared by all in-flight requests, plus per-slot state arrays for the
 cache types that are O(1) or latent-compressed per token.
 
 Layout (mirrors ``params["layers"]`` / ``decode.init_caches`` so the paged
 decode step scans layers and pool state together):
 
-  attn   -> {"k": (n_j, N, bs, KV, hd), "v": ...}   one arena per layer; a
-            physical block id addresses the same (bs, KV, hd) slab in every
-            layer's arena, so one block table serves the whole stack
+  attn   -> {"k": (n_j, N, KV, bs, hd), "v": ...}   one arena per layer; a
+            physical block id addresses the same (KV, bs, hd) slab in every
+            layer's arena, so one block table serves the whole stack; a
+            block of one KV head is a (bs, hd) tile, the unit the paged
+            attention kernel copies
   mla    -> {"mla": MLACache((n_j, S, cap, kv_lora), ...)}  per-slot rows
   rwkv   -> {"rwkv": RWKVState((n_j, S, H, dk, dk), ...)}   per-slot rows
   rglru  -> {"rglru": RGLRUState((n_j, S, dr), ...)}        per-slot rows
@@ -302,7 +304,7 @@ def init_pool_caches(cfg: ModelConfig, params: dict, pool: PoolConfig,
 
         def one(mixer):
             if mixer == "attn":
-                shape = (num_blocks, pool.block_size, cfg.n_kv, cfg.hd)
+                shape = (num_blocks, cfg.n_kv, pool.block_size, cfg.hd)
                 return {"k": jnp.zeros(shape, dtype),
                         "v": jnp.zeros(shape, dtype)}
             return decmod.init_layer_cache(cfg, mixer, pool.max_slots,

@@ -4,7 +4,7 @@ One definition of the mixed-length churn workload, the tiny-arch factory,
 and the small paged pool, imported by test_paged_engine.py,
 test_prefix_cache.py, test_speculative.py and
 test_paged_attention_kernel.py (tests/ is on sys.path via pytest rootdir
-insertion, like _hypothesis_compat).
+insertion).
 """
 from __future__ import annotations
 

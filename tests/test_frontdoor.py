@@ -311,7 +311,9 @@ def test_http_smoke_stream_and_clean_shutdown():
     """Boot the front door as a subprocess, stream one generation over SSE
     via the bundled client, hit /healthz, then SIGTERM and require a clean
     exit — the same probe CI's serve-smoke leg runs."""
-    env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
+    # the CLI turns the persistent compile cache on; a test run keeps it off
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"),
+               JAX_ENABLE_COMPILATION_CACHE="false")
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.launch.serve", "--arch", "llama2-7b",
          "--tiny", "--serve", "--port", "0", "--slots", "2",

@@ -15,7 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from helpers import mixed_requests, noisy, small_pool, tiny
 
 from repro.kernels.paged_attention import ops as pops
@@ -36,8 +37,8 @@ def _pool_state(rng, b, w, kv, g, hd, bs, mb, window, wrapped, dtype):
     ring_blocks = rng.integers(1, mb + 1, size=b)
     n_phys = 1 + int(ring_blocks.sum())
     q = rng.normal(size=(b, w, h, hd)).astype(np.float32)
-    k_arena = rng.normal(size=(n_phys, bs, kv, hd)).astype(dtype)
-    v_arena = rng.normal(size=(n_phys, bs, kv, hd)).astype(dtype)
+    k_arena = rng.normal(size=(n_phys, kv, bs, hd)).astype(dtype)
+    v_arena = rng.normal(size=(n_phys, kv, bs, hd)).astype(dtype)
     bt = np.zeros((b, mb), np.int32)
     nxt = 1
     for i in range(b):

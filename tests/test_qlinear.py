@@ -3,7 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import packing
 from repro.core.qlinear import (QuantizedGrouped, quantize_grouped,
@@ -78,7 +79,9 @@ def test_packing_roundtrip(bits):
     u = packing.unpack_codes(p, bits, 301)
     assert (u == codes).all()
     if bits in (1, 2, 4):
-        assert p.shape[0] == -(-301 // (8 // bits))
+        # whole PACK_ROWS tiles of 8 // bits codes per byte
+        assert p.shape[0] == -(-301 // packing.PACK_ROWS) * (
+            packing.PACK_ROWS // (8 // bits))
 
 
 def test_grouped_apply_matches_per_expert():

@@ -75,8 +75,8 @@ def test_paged_ring_matches_dense_ring_attention():
     ring_blocks = -(-window // bs)                # 2 blocks -> ring cap 8
     ring_cap = ring_blocks * bs
     n_blocks = 1 + ring_blocks                    # + null block
-    k_arena = jnp.zeros((n_blocks, bs, kv, hd))
-    v_arena = jnp.zeros((n_blocks, bs, kv, hd))
+    k_arena = jnp.zeros((n_blocks, kv, bs, hd))
+    v_arena = jnp.zeros((n_blocks, kv, bs, hd))
     bt = jnp.asarray([[1, 2]], jnp.int32)
     dense = KVCache.init(b, window, kv, hd)
     for pos in range(15):                         # wraps both rings
@@ -90,8 +90,8 @@ def test_paged_ring_matches_dense_ring_attention():
         pb, off = attnmod.paged_write_indices(
             jnp.asarray([pos], jnp.int32), jnp.asarray([ring_cap], jnp.int32),
             bt, bs, jnp.asarray([True]))
-        k_arena = k_arena.at[pb, off].set(kk[:, 0])
-        v_arena = v_arena.at[pb, off].set(vv[:, 0])
+        k_arena = attnmod.paged_write(k_arena, pb, off, kk[:, 0])
+        v_arena = attnmod.paged_write(v_arena, pb, off, vv[:, 0])
         ref = decode_attention(q, dense, jnp.int32(pos + 1))
         got = attnmod.paged_decode_attention(
             q, k_arena, v_arena, bt, jnp.asarray([pos + 1], jnp.int32),
@@ -107,8 +107,8 @@ def test_paged_full_context_matches_dense_cache():
     key = jax.random.PRNGKey(4)
     b, kv, h, hd, bs, cap = 1, 2, 2, 8, 4, 12
     nb = cap // bs
-    k_arena = jnp.zeros((1 + nb, bs, kv, hd))
-    v_arena = jnp.zeros((1 + nb, bs, kv, hd))
+    k_arena = jnp.zeros((1 + nb, kv, bs, hd))
+    v_arena = jnp.zeros((1 + nb, kv, bs, hd))
     bt = jnp.asarray([[1, 2, 3]], jnp.int32)
     dense = KVCache.init(b, cap, kv, hd)
     for pos in range(cap):
@@ -120,8 +120,8 @@ def test_paged_full_context_matches_dense_cache():
         pb, off = attnmod.paged_write_indices(
             jnp.asarray([pos], jnp.int32), jnp.asarray([cap], jnp.int32),
             bt, bs, jnp.asarray([True]))
-        k_arena = k_arena.at[pb, off].set(kk[:, 0])
-        v_arena = v_arena.at[pb, off].set(vv[:, 0])
+        k_arena = attnmod.paged_write(k_arena, pb, off, kk[:, 0])
+        v_arena = attnmod.paged_write(v_arena, pb, off, vv[:, 0])
         ref = decode_attention(q, dense, jnp.int32(pos + 1))
         got = attnmod.paged_decode_attention(
             q, k_arena, v_arena, bt, jnp.asarray([pos + 1], jnp.int32),

@@ -36,6 +36,19 @@ preserving the target distribution exactly (greedy mode is token-identical
 to non-speculative decoding).  Attention archs only; recurrent/MLA archs
 bypass speculation because their sequential per-slot state cannot absorb
 rejected positions.
+
+Tracing: every layer boundary of an iteration runs inside a
+``tracing.span`` (``serve.step`` around ``admit`` (with ``cow``),
+``prefill``, and ``decode`` or ``spec``, each of the last three split into
+``.inputs``, ``.dispatch``, ``.pull`` and ``.sample``), whose host time and
+count accumulate in ``stats["span_s.*"]`` / ``stats["span_n.*"]``.  Beside
+them ``stats`` holds cumulative counters of the waits before a request's
+first chunk: ``admitted`` / ``queue_wait_s`` (arrival to admission) and
+``prefill_started`` / ``prefill_wait_s`` (admission to the first chunk),
+and ``prefill_slot_steps`` (slots held by prefilling requests, summed over
+decode steps).  ``PROGRAMS`` lists every jitted program by attribute; its
+compiled module is ``jit_<name>`` (``jit__step``, ``jit__chunk``), the
+names a trace reduction may rely on.
 """
 from __future__ import annotations
 
@@ -57,6 +70,12 @@ from repro.runtime import tp as tpmod
 
 from .pool import (BlockAllocator, PoolConfig, PrefixCache, init_pool_caches,
                    request_blocks)
+from .tracing import span
+
+# Every jitted program of a PagedServer, by attribute name.  Each compiles
+# to an HLO module named ``jit_<name>``; a trace reduction may rely on
+# these names, so they do not change.
+PROGRAMS = ("_step", "_chunk", "_verify", "_draft_step", "_catchup", "_cow")
 
 
 @dataclasses.dataclass
@@ -129,6 +148,7 @@ class _InFlight:
     preemptions: int = 0
     t_admit: float = 0.0
     t_first: float = 0.0
+    awaits_chunk: bool = False       # first admission, no chunk run yet
     chain: object = None             # prefix-cache hash of last full block
     n_hashed: int = 0                # full blocks matched/registered so far
     draft_pos: int = 0               # draft-KV-valid positions (speculation)
@@ -361,7 +381,10 @@ class PagedServer:
             # clone one physical block's KV across every layer arena
             return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), caches)
 
-        _cow = tpmod.sharded_cache_op(_cow_core, self.mesh, self._cspecs)
+        cow_op = tpmod.sharded_cache_op(_cow_core, self.mesh, self._cspecs)
+
+        def _cow(caches, src, dst):
+            return cow_op(caches, src, dst)
 
         self._step = jax.jit(_step, donate_argnums=(1,))
         self._draft_step = jax.jit(_draft_step, donate_argnums=(1,))
@@ -502,13 +525,14 @@ class PagedServer:
             if cow_src is not None:
                 # fresh[0] sits at logical index len(hits) — exactly where
                 # the partially-matching block's contents belong
-                self.caches = self._cow(self.caches, jnp.int32(cow_src),
-                                        jnp.int32(fresh[0]))
-                if self.speculating:
-                    # the draft arena shares block tables: clone its copy too
-                    self.draft_caches = self._cow(self.draft_caches,
-                                                  jnp.int32(cow_src),
-                                                  jnp.int32(fresh[0]))
+                with span(self.stats, "cow"):
+                    self.caches = self._cow(self.caches, jnp.int32(cow_src),
+                                            jnp.int32(fresh[0]))
+                    if self.speculating:
+                        # the draft arena shares block tables: clone it too
+                        self.draft_caches = self._cow(self.draft_caches,
+                                                      jnp.int32(cow_src),
+                                                      jnp.int32(fresh[0]))
                 self.allocator.decref(cow_src)
                 self.stats["prefix_cow"] = self.stats.get("prefix_cow", 0) + 1
             blocks = hits + fresh
@@ -528,6 +552,10 @@ class PagedServer:
                         self.stats.get("prefix_hits", 0) + 1)
             if rp is not None:
                 self.stats["replays"] = self.stats.get("replays", 0) + 1
+            else:
+                self.stats["admitted"] = self.stats.get("admitted", 0) + 1
+                self.stats["queue_wait_s"] = (
+                    self.stats.get("queue_wait_s", 0.0) + now - req.arrival)
             self._prefilling.append(_InFlight(
                 req=req, slot=slot, blocks=blocks, bt_row=bt_row,
                 ring_cap=ring_cap, served=served, filled=cached,
@@ -535,7 +563,7 @@ class PagedServer:
                 token_times=list(rp.token_times) if rp else [],
                 preemptions=rp.preemptions if rp else 0,
                 t_admit=rp.t_admit if rp else now,
-                t_first=rp.t_first if rp else 0.0,
+                t_first=rp.t_first if rp else 0.0, awaits_chunk=rp is None,
                 chain=parent, n_hashed=len(hits), draft_pos=cached))
 
     def _register_blocks(self, st: _InFlight, seq, upto: int) -> None:
@@ -590,71 +618,91 @@ class PagedServer:
         c = min(self.pool.prefill_chunk, plen - st.filled)
         if self.has_attn:
             c = min(c, st.ring_cap)   # scatter uniqueness within a chunk
-        toks = jnp.asarray(st.served[st.filled:st.filled + c],
-                           jnp.int32)[None]
-        with self._kernel_scope():
-            logits, self.caches = self._chunk(
-                self.params, self.caches, toks, jnp.int32(st.filled),
-                jnp.int32(st.slot), jnp.asarray(st.bt_row),
-                jnp.int32(st.ring_cap))
+        with span(self.stats, "prefill", rid=st.req.rid, tokens=c):
+            if st.awaits_chunk:
+                st.awaits_chunk = False
+                self.stats["prefill_started"] = (
+                    self.stats.get("prefill_started", 0) + 1)
+                self.stats["prefill_wait_s"] = (
+                    self.stats.get("prefill_wait_s", 0.0)
+                    + time.monotonic() - t0 - st.t_admit)
+            with span(self.stats, "prefill.inputs"):
+                args = (jnp.asarray(st.served[st.filled:st.filled + c],
+                                    jnp.int32)[None],
+                        jnp.int32(st.filled), jnp.int32(st.slot),
+                        jnp.asarray(st.bt_row), jnp.int32(st.ring_cap))
+            with span(self.stats, "prefill.dispatch"), self._kernel_scope():
+                logits, self.caches = self._chunk(self.params, self.caches,
+                                                  *args)
+                if self.speculating:
+                    # the draft arena must hold the prompt too — prefill it
+                    # in the same chunks (cheap: the draft's packed codes
+                    # are the low-budget quantization); its logits are unused
+                    _, self.draft_caches = self._chunk(
+                        self.draft_params, self.draft_caches, *args)
+            st.filled += c
             if self.speculating:
-                # the draft arena must hold the prompt too — prefill it in
-                # the same chunks (cheap: the draft's packed codes are the
-                # low-budget quantization); its logits are unused
-                _, self.draft_caches = self._chunk(
-                    self.draft_params, self.draft_caches, toks,
-                    jnp.int32(st.filled), jnp.int32(st.slot),
-                    jnp.asarray(st.bt_row), jnp.int32(st.ring_cap))
-        st.filled += c
-        if self.speculating:
-            st.draft_pos = st.filled
-        self.stats["prefill_chunks"] = self.stats.get("prefill_chunks", 0) + 1
-        self.stats["prefill_tokens"] = self.stats.get("prefill_tokens", 0) + c
-        if self.prefix_cache is not None:
-            # blocks completed by this chunk are fully written: publish them
-            # so concurrent requests sharing the prompt hit them immediately
-            self._register_blocks(st, st.served, st.filled)
-        if st.filled == plen:
+                st.draft_pos = st.filled
+            self.stats["prefill_chunks"] = (
+                self.stats.get("prefill_chunks", 0) + 1)
+            self.stats["prefill_tokens"] = (
+                self.stats.get("prefill_tokens", 0) + c)
+            if self.prefix_cache is not None:
+                # blocks completed by this chunk are fully written: publish
+                # them so concurrent requests sharing the prompt hit them
+                # immediately
+                self._register_blocks(st, st.served, st.filled)
+            if st.filled < plen:
+                return
             self._prefilling.popleft()
-            tok = self._sample(np.asarray(logits[0]), st.req.rid, st.n_done)
-            now = time.monotonic() - t0       # after the step has completed
-            self._emit(st, tok, now)
-            if st.n_done >= st.req.max_new or tok == st.req.eos:
+            with span(self.stats, "prefill.pull"):
+                row = np.asarray(logits[0])
+            with span(self.stats, "prefill.sample"):
+                tok = self._sample(row, st.req.rid, st.n_done)
+                now = time.monotonic() - t0   # after the step has completed
+                self._emit(st, tok, now)
                 self._active[st.slot] = st   # _finish expects it registered
-                self._finish(st, now, results)
-            else:
-                self._active[st.slot] = st
+                if st.n_done >= st.req.max_new or tok == st.req.eos:
+                    self._finish(st, now, results)
 
     def _decode_once(self, t0: float,
                      results: dict[int, RequestResult]) -> None:
         s = self.pool.max_slots
-        tokens = np.zeros((s, 1), np.int32)
-        pos = np.zeros(s, np.int32)
-        active = np.zeros(s, bool)
-        bts = np.zeros((s, self.table_width), np.int32)
-        ring = np.ones(s, np.int32)
-        for slot, st in self._active.items():
-            tokens[slot, 0] = st.out[-1]
-            pos[slot] = len(st.served) + len(st.out) - 1
-            active[slot] = True
-            bts[slot] = st.bt_row
-            ring[slot] = st.ring_cap
-        with self._kernel_scope():
-            logits, self.caches = self._step(
-                self.params, self.caches, jnp.asarray(tokens),
-                jnp.asarray(pos), jnp.asarray(active), jnp.asarray(bts),
-                jnp.asarray(ring))
-        logits = np.asarray(logits)
-        now = time.monotonic() - t0           # after the step has completed
-        self.stats["decode_steps"] = self.stats.get("decode_steps", 0) + 1
+        with span(self.stats, "decode.inputs"):
+            tokens = np.zeros((s, 1), np.int32)
+            pos = np.zeros(s, np.int32)
+            active = np.zeros(s, bool)
+            bts = np.zeros((s, self.table_width), np.int32)
+            ring = np.ones(s, np.int32)
+            for slot, st in self._active.items():
+                tokens[slot, 0] = st.out[-1]
+                pos[slot] = len(st.served) + len(st.out) - 1
+                active[slot] = True
+                bts[slot] = st.bt_row
+                ring[slot] = st.ring_cap
+            args = tuple(map(jnp.asarray, (tokens, pos, active, bts, ring)))
+        with span(self.stats, "decode.dispatch"), self._kernel_scope():
+            logits, self.caches = self._step(self.params, self.caches, *args)
+        with span(self.stats, "decode.pull"):
+            logits = np.asarray(logits)
+        with span(self.stats, "decode.sample"):
+            now = time.monotonic() - t0       # after the step has completed
+            self._count_decode_step("decode_steps")
+            for slot in list(self._active):
+                st = self._active[slot]
+                tok = self._sample(logits[slot], st.req.rid, st.n_done)
+                self._emit(st, tok, now)
+                if st.n_done >= st.req.max_new or tok == st.req.eos:
+                    self._finish(st, now, results)
+
+    def _count_decode_step(self, key: str) -> None:
+        """Count one decode step (``key``) with the slots it served
+        (``occupancy``) and those held by requests still prefilling."""
+        self.stats[key] = self.stats.get(key, 0) + 1
         self.stats.setdefault("occupancy", []).append(
             len(self._active) / self.pool.max_slots)
-        for slot in list(self._active):
-            st = self._active[slot]
-            tok = self._sample(logits[slot], st.req.rid, st.n_done)
-            self._emit(st, tok, now)
-            if st.n_done >= st.req.max_new or tok == st.req.eos:
-                self._finish(st, now, results)
+        self.stats["prefill_slot_steps"] = (
+            self.stats.get("prefill_slot_steps", 0) + len(self._prefilling))
 
     # ---------------------------------------------------------- speculation
 
@@ -672,86 +720,90 @@ class PagedServer:
         slot (``speculative_accept``), emitting 1..k+1 tokens per round.
         """
         s, k = self.pool.max_slots, self.speculate
-        catch = np.zeros((s, 2), np.int32)    # tokens at pos-1, pos
-        pos = np.zeros(s, np.int32)
-        active = np.zeros(s, bool)
-        hole = np.zeros(s, bool)
-        bts = np.zeros((s, self.table_width), np.int32)
-        ring = np.ones(s, np.int32)
-        for slot, st in self._active.items():
-            p = len(st.served) + len(st.out) - 1
-            pos[slot] = p
-            catch[slot, 0] = (st.out[-2] if len(st.out) >= 2
-                              else st.served[-1])
-            catch[slot, 1] = st.out[-1]
-            active[slot] = True
-            # after an all-accept round the bonus token's predecessor (d_k)
-            # was never fed to the draft: position p-1 is a hole the
-            # catch-up step must commit; otherwise the rewrite is masked so
-            # shared prefix-cache blocks are never touched
-            hole[slot] = st.draft_pos == p - 1
-            bts[slot] = st.bt_row
-            ring[slot] = st.ring_cap
-        wmask = np.ones((s, 2), bool)
-        wmask[:, 0] = hole
-        with self._kernel_scope():
+        with span(self.stats, "spec.inputs"):
+            catch = np.zeros((s, 2), np.int32)    # tokens at pos-1, pos
+            pos = np.zeros(s, np.int32)
+            active = np.zeros(s, bool)
+            hole = np.zeros(s, bool)
+            bts = np.zeros((s, self.table_width), np.int32)
+            ring = np.ones(s, np.int32)
+            for slot, st in self._active.items():
+                p = len(st.served) + len(st.out) - 1
+                pos[slot] = p
+                catch[slot, 0] = (st.out[-2] if len(st.out) >= 2
+                                  else st.served[-1])
+                catch[slot, 1] = st.out[-1]
+                active[slot] = True
+                # after an all-accept round the bonus token's predecessor
+                # (d_k) was never fed to the draft: position p-1 is a hole
+                # the catch-up step must commit; otherwise the rewrite is
+                # masked so shared prefix-cache blocks are never touched
+                hole[slot] = st.draft_pos == p - 1
+                bts[slot] = st.bt_row
+                ring[slot] = st.ring_cap
+            wmask = np.ones((s, 2), bool)
+            wmask[:, 0] = hole
+            tables = tuple(map(jnp.asarray, (active, bts, ring)))
+            catch_args = (jnp.asarray(catch), jnp.asarray(pos - 1), *tables,
+                          jnp.asarray(wmask))
+        with span(self.stats, "spec.dispatch"), self._kernel_scope():
             dlog, self.draft_caches = self._catchup(
-                self.draft_params, self.draft_caches, jnp.asarray(catch),
-                jnp.asarray(pos - 1), jnp.asarray(active), jnp.asarray(bts),
-                jnp.asarray(ring), jnp.asarray(wmask))
-        dl = np.asarray(dlog[:, 1])           # draft logits at position pos
+                self.draft_params, self.draft_caches, *catch_args)
+        with span(self.stats, "spec.pull"):
+            dl = np.asarray(dlog[:, 1])       # draft logits at position pos
         draft_logits = np.zeros((s, k) + dl.shape[1:], np.float32)
         draft_tokens = np.zeros((s, k), np.int32)
         toks = np.zeros((s, 1), np.int32)
         for i in range(k):
-            draft_logits[:, i] = dl
-            for slot, st in self._active.items():
-                d = self._draft_sample(dl[slot], st.req.rid, st.n_done, i)
-                draft_tokens[slot, i] = d
-                toks[slot, 0] = d
+            with span(self.stats, "spec.sample"):
+                draft_logits[:, i] = dl
+                for slot, st in self._active.items():
+                    d = self._draft_sample(dl[slot], st.req.rid, st.n_done,
+                                           i)
+                    draft_tokens[slot, i] = d
+                    toks[slot, 0] = d
             if i < k - 1:
-                with self._kernel_scope():
+                with span(self.stats, "spec.dispatch"), self._kernel_scope():
                     nxt, self.draft_caches = self._draft_step(
                         self.draft_params, self.draft_caches,
-                        jnp.asarray(toks), jnp.asarray(pos + 1 + i),
-                        jnp.asarray(active), jnp.asarray(bts),
-                        jnp.asarray(ring))
-                dl = np.asarray(nxt)
-        verify_toks = np.concatenate([catch[:, 1:2], draft_tokens], axis=1)
-        with self._kernel_scope():
+                        jnp.asarray(toks), jnp.asarray(pos + 1 + i), *tables)
+                with span(self.stats, "spec.pull"):
+                    dl = np.asarray(nxt)
+        with span(self.stats, "spec.dispatch"), self._kernel_scope():
+            verify_toks = np.concatenate([catch[:, 1:2], draft_tokens],
+                                         axis=1)
             tlog, self.caches = self._verify(
                 self.params, self.caches, jnp.asarray(verify_toks),
-                jnp.asarray(pos), jnp.asarray(active), jnp.asarray(bts),
-                jnp.asarray(ring), jnp.ones((s, k + 1), bool))
-        tlog = np.asarray(tlog)
-        now = time.monotonic() - t0           # after the step has completed
-        self.stats["spec_rounds"] = self.stats.get("spec_rounds", 0) + 1
-        self.stats.setdefault("occupancy", []).append(
-            len(self._active) / self.pool.max_slots)
-        for slot in list(self._active):
-            st = self._active[slot]
-            # greedy needs no RNG (and warmup requests may carry negative
-            # rids, which SeedSequence rejects)
-            rng = (np.random.default_rng(
-                       (self.seed, st.req.rid, st.n_done, 7))
-                   if self.temperature > 0.0 else None)
-            emitted, n_acc = speculative_accept(
-                tlog[slot], draft_logits[slot], draft_tokens[slot],
-                self.temperature, rng)
-            self.stats["spec_proposed"] = (
-                self.stats.get("spec_proposed", 0) + k)
-            self.stats["spec_accepted"] = (
-                self.stats.get("spec_accepted", 0) + n_acc)
-            p = int(pos[slot])
-            # draft KV is valid through the last accepted draft position
-            # (the replacement/bonus token is never fed to the draft)
-            st.draft_pos = min(p + n_acc + 1, p + k)
-            for tok in emitted:
-                self._emit(st, tok, now)
-                if (st.n_done >= st.req.max_new or tok == st.req.eos):
-                    break
-            if st.n_done >= st.req.max_new or st.out[-1] == st.req.eos:
-                self._finish(st, now, results)
+                jnp.asarray(pos), *tables, jnp.ones((s, k + 1), bool))
+        with span(self.stats, "spec.pull"):
+            tlog = np.asarray(tlog)
+        with span(self.stats, "spec.sample"):
+            now = time.monotonic() - t0       # after the step has completed
+            self._count_decode_step("spec_rounds")
+            for slot in list(self._active):
+                st = self._active[slot]
+                # greedy needs no RNG (and warmup requests may carry
+                # negative rids, which SeedSequence rejects)
+                rng = (np.random.default_rng(
+                           (self.seed, st.req.rid, st.n_done, 7))
+                       if self.temperature > 0.0 else None)
+                emitted, n_acc = speculative_accept(
+                    tlog[slot], draft_logits[slot], draft_tokens[slot],
+                    self.temperature, rng)
+                self.stats["spec_proposed"] = (
+                    self.stats.get("spec_proposed", 0) + k)
+                self.stats["spec_accepted"] = (
+                    self.stats.get("spec_accepted", 0) + n_acc)
+                p = int(pos[slot])
+                # draft KV is valid through the last accepted draft position
+                # (the replacement/bonus token is never fed to the draft)
+                st.draft_pos = min(p + n_acc + 1, p + k)
+                for tok in emitted:
+                    self._emit(st, tok, now)
+                    if (st.n_done >= st.req.max_new or tok == st.req.eos):
+                        break
+                if st.n_done >= st.req.max_new or st.out[-1] == st.req.eos:
+                    self._finish(st, now, results)
 
     # --------------------------------------------------------- preemption
 
@@ -863,24 +915,27 @@ class PagedServer:
         drain loop over this; a front door calls it forever."""
         results: dict[int, RequestResult] = {}
         self.start_clock()
-        self._try_admit(self.now())
-        if prefill and self._prefilling:
-            self._prefill_one(self._t0, results)
-        if self._active:
-            if self.speculate:
-                self._spec_decode_once(self._t0, results)
+        with span(self.stats, "step"):
+            with span(self.stats, "admit"):
+                self._try_admit(self.now())
+            if prefill and self._prefilling:
+                self._prefill_one(self._t0, results)
+            if self._active:
+                if self.speculate:
+                    with span(self.stats, "spec", slots=len(self._active)):
+                        self._spec_decode_once(self._t0, results)
+                else:
+                    with span(self.stats, "decode",
+                              slots=len(self._active)):
+                        self._decode_once(self._t0, results)
+                end = time.monotonic()
+                if self._last_decode_end is not None:
+                    self.decode_gaps.append(end - self._last_decode_end)
+                self._last_decode_end = end
             else:
-                self._decode_once(self._t0, results)
-            end = time.monotonic()
-            if self._last_decode_end is not None:
-                gap = end - self._last_decode_end
-                self.decode_gaps.append(gap)
-                self.stats.setdefault("decode_gap_s", []).append(gap)
-            self._last_decode_end = end
-        else:
-            # no decode ran: the next gap would measure idleness, not
-            # scheduling interference — restart the gap baseline
-            self._last_decode_end = None
+                # no decode ran: the next gap would measure idleness, not
+                # scheduling interference — restart the gap baseline
+                self._last_decode_end = None
         return results
 
     def finalize_stats(self) -> dict:

@@ -195,7 +195,6 @@ class FrontDoor:
                     "slo_last_p95_ms": self.scheduler.last_p95_ms}
         # the raw per-step lists are internal accounting, not API surface
         snap["engine"].pop("occupancy", None)
-        snap["engine"].pop("decode_gap_s", None)
         return json.dumps(snap, default=_json_default).encode()
 
     # ----------------------------------------------------------- generation

@@ -20,7 +20,8 @@ from hypothesis import strategies as st
 from helpers import mixed_requests, noisy, small_pool, tiny
 
 from repro.kernels.paged_attention import ops as pops
-from repro.kernels.paged_attention.paged import paged_attention_pallas
+from repro.kernels.paged_attention.paged import (paged_attention_pallas,
+                                                pages_per_block)
 from repro.kernels.paged_attention.ref import paged_attention_ref
 from repro.models import attention as attnmod
 from repro.models import transformer as tf
@@ -88,6 +89,92 @@ def test_kernel_matches_reference_bf16_arena():
     out_r = paged_attention_ref(q, ka, va, bt, pos, ring)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                atol=2e-2, rtol=2e-2)
+
+
+# ------------------------------------------- multi-page compute blocks
+# The kernel walks a slot's live pages in compute blocks of P pages, P
+# derived from the shapes (``pages_per_block``).  These cases use the batch
+# cell's page (8 KV heads of 128, 16-token blocks), where P < MB, and set
+# MB = P + 3 so the last compute block of a full table is partial.
+
+KV8, G2, HD128, BS16 = 8, 2, 128, 16
+
+
+def _slot_rows(rng, slots, w, mb, dtype):
+    """A pool state from explicit slots: ``("live", n)`` holds a full table
+    of ``mb`` distinct blocks of which the first ``n`` hold keys;
+    ``("ring", r)`` has a ring of ``r`` blocks written 1-3 times round;
+    ``None`` is an inactive slot as the engine passes it (pos 1, ring 1,
+    every entry on the null block 0), where only the last of ``w`` queries
+    has a key."""
+    b, h = len(slots), KV8 * G2
+    bt = np.zeros((b, mb), np.int32)
+    pos = np.ones(b, np.int32)
+    ring = np.ones(b, np.int32)
+    nxt = 1
+    for i, slot in enumerate(slots):
+        if slot is None:
+            continue
+        kind, n = slot
+        width = mb if kind == "live" else n
+        bt[i, :width] = np.arange(nxt, nxt + width)
+        nxt += width
+        ring[i] = width * BS16
+        if kind == "live":      # the n-th block is the last one holding keys
+            pos[i] = rng.integers(max((n - 1) * BS16 + 1, w), n * BS16 + 1)
+        else:
+            pos[i] = rng.integers(n * BS16 + 1, 3 * n * BS16 + 1)
+    q = rng.normal(size=(b, w, h, HD128)).astype(np.float32)
+    k_arena = rng.normal(size=(nxt, KV8, BS16, HD128)).astype(np.float32)
+    v_arena = rng.normal(size=(nxt, KV8, BS16, HD128)).astype(np.float32)
+    return (jnp.asarray(q), jnp.asarray(k_arena, dtype),
+            jnp.asarray(v_arena, dtype), jnp.asarray(bt), jnp.asarray(pos),
+            jnp.asarray(ring))
+
+
+def _assert_kernel_matches(state, dtype, window=None):
+    """Kernel vs gather reference on every query that has a key (a query
+    with none reads 0 from the kernel and the mean of V from the
+    reference; the engine never reads it)."""
+    out_k = np.asarray(paged_attention_pallas(*state, window=window,
+                                              interpret=True))
+    out_r = np.asarray(paged_attention_ref(*state, window=window))
+    has_key = np.ones(out_k.shape[:2], bool)
+    has_key[np.asarray(state[4]) == 1, :-1] = False
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(out_k[has_key], out_r[has_key],
+                               atol=tol, rtol=tol,
+                               err_msg=f"pos={np.asarray(state[4])}")
+
+
+@pytest.mark.parametrize("live", ["1", "P-1", "P", "P+1", "MB"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_kernel_live_pages_across_compute_blocks(live, dtype):
+    """A slot whose live pages end before, at and after a compute block's
+    edge, and on a full table whose last compute block is partial, beside
+    an inactive slot and a slot with a full table."""
+    p = pages_per_block(64, KV8, BS16, HD128, dtype)
+    mb = p + 3
+    assert 2 <= p and mb % p != 0
+    n = {"1": 1, "P-1": p - 1, "P": p, "P+1": p + 1, "MB": mb}[live]
+    rng = np.random.default_rng(n)
+    state = _slot_rows(rng, [("live", n), None, ("live", mb)], 1, mb, dtype)
+    _assert_kernel_matches(state, dtype)
+
+
+@pytest.mark.parametrize("w", [1, 2, 4])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_kernel_ring_and_window_over_compute_blocks(w, dtype):
+    """Wrapped rings longer than a compute block and a sliding window that
+    spans one, for decode (W=1), catch-up (W=2) and verify (W=4) spans."""
+    p = pages_per_block(64, KV8, BS16, HD128, dtype)
+    mb = p + 3
+    rng = np.random.default_rng(100 + w)
+    state = _slot_rows(rng, [("ring", p + 2), None, ("ring", 2),
+                             ("live", p + 1)], w, mb, dtype)
+    _assert_kernel_matches(state, dtype, window=(p + 1) * BS16 - 5)
 
 
 def test_reference_matches_pre_kernel_decode_math():

@@ -88,14 +88,12 @@ def test_grouped_fused_qmatmul_compiles(one_chip):
     assert '"tpu_custom_call"' in hlo
 
 
-@pytest.mark.parametrize("w", [1, 3])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_attention_compiles(one_chip, w, dtype):
-    """Decode (W=1) and speculative verify (W=k+1) over f32 and bf16
-    arenas laid out (N, KV, bs, hd)."""
-    b, h, kv, hd, bs, n_blocks, mb = 8, 16, 8, 128, 16, 73, 9
+def _paged_custom_calls(sharding, b, w, h, kv, hd, bs, n_blocks, mb,
+                        dtype) -> list[str]:
+    """The ``tpu_custom_call`` instructions of the paged-attention kernel
+    compiled at these shapes."""
     spec = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
-        shape, dt, sharding=one_chip)
+        shape, dt, sharding=sharding)
     hlo = _compile(
         lambda *a: paged_attention_pallas(*a),
         spec((b, w, h, hd), jnp.float32),
@@ -103,4 +101,26 @@ def test_paged_attention_compiles(one_chip, w, dtype):
         spec((n_blocks, kv, bs, hd), dtype),
         spec((b, mb), jnp.int32), spec((b,), jnp.int32),
         spec((b,), jnp.int32))
-    assert hlo.count('"tpu_custom_call"') == 1
+    return [line for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("w", [1, 3])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_compiles(one_chip, w, dtype):
+    """Decode (W=1) and speculative verify (W=k+1) over f32 and bf16
+    arenas laid out (N, KV, bs, hd)."""
+    calls = _paged_custom_calls(one_chip, 8, w, 16, 8, 128, 16, 73, 9, dtype)
+    assert len(calls) == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_paged_attention_compiles_at_batch_cell(one_chip, dtype):
+    """The batch cell's decode call: 64 slots, 16/8 heads of 128, 16-token
+    blocks, a 56-entry table over 2,800 blocks.  Its page buffers (P pages
+    of K and V, twice) fit VMEM for the bf16 arena and for the f32 one,
+    and the kernel keeps the name the trace reduction keys on."""
+    calls = _paged_custom_calls(one_chip, 64, 1, 16, 8, 128, 16, 2800, 56,
+                                dtype)
+    assert len(calls) == 1
+    assert calls[0].lstrip().startswith("%paged_attention")

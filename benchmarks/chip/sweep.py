@@ -3,8 +3,12 @@
 window per rate, drained between rates, in one process on the chip.  A
 rate is sustained while the requests in the system at the window's close
 stay near the slot count and the time to first token of the window's last
-third stays near its first third's.  Not part of a benchmark run; its
-result is written into the traffic file by hand, once.
+third stays near its first third's.  Each rate is served turns of its
+own, with the same histories, sizes and gaps: a turn an earlier rate
+served would be found whole in the prefix cache, which no run sees.  A
+rate whose window compiled anything (``compiles``) reads nothing sound.
+Not part of a benchmark run; its result is written into the traffic file
+by hand, once.
 
     python3 benchmarks/chip/sweep.py --workload <cell> --seed <n> \
         --seconds <s> --rates 0.3,0.5,0.7
@@ -23,6 +27,20 @@ import sys  # noqa: E402
 import run  # noqa: E402
 
 
+def fresh_traffic(mix: dict, seed: int, vocab: int, salt: int):
+    """The mix's ``Traffic`` for ``seed`` whose turns are drawn afresh for
+    ``salt``; histories, sizes and arrival gaps are the seed's own."""
+    from chipbench.traffic import Traffic
+
+    class Fresh(Traffic):
+        def _tokens(self, tag, n):
+            if tag[0] == "turn":
+                tag = tag + ("rate", salt)
+            return super()._tokens(tag, n)
+
+    return Fresh(mix, seed, vocab)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -35,6 +53,7 @@ def main(argv=None) -> int:
     from chipbench import cell as cellmod
     from chipbench import device, session
     from chipbench.traffic import Traffic, percentile
+    meter = device.CompileMeter()
     c = cellmod.find_cell(bench, args.workload)
     device.require_devices(c["chips"])
     conf = cellmod.load_json(cellmod.HERE / "configs" / f"{c['config']}.json")
@@ -51,10 +70,11 @@ def main(argv=None) -> int:
             engine.pool.prefill_chunk, engine.pool.block_size), args.seed,
             cow=bool(base.histories))
         session.prefill_histories(engine, base.histories)
-        for rate in [float(r) for r in args.rates.split(",")]:
+        for i, rate in enumerate(float(r) for r in args.rates.split(",")):
             m = copy.deepcopy(mix)
             m["arrivals"]["rate_per_s"] = rate
-            tr = Traffic(m, args.seed, cfg.vocab)
+            tr = fresh_traffic(m, args.seed, cfg.vocab, i)
+            compiles0 = meter.count
             win = session.Window(engine, tr, session.Spans(False))
             win.run(args.seconds)
             lo, hi = win.start, win.end
@@ -71,6 +91,7 @@ def main(argv=None) -> int:
             due = [r for r in win.recs.values() if lo <= r.due < hi]
             print(json.dumps({
                 "rate": rate, "due": len(due),
+                "compiles": meter.count - compiles0,
                 "finished": sum(r.done for r in due),
                 "in_system_at_close": sum(not r.done for r in due),
                 "ttft_p50_first_third_s": ttft[0],

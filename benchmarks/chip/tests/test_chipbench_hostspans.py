@@ -166,12 +166,9 @@ def test_traced_tiny_run_reads_the_counters():
     metrics read, and with ``occupancy`` they account for the held slots.
     A CPU trace has no TPU plane, so the idle parts read nothing, as
     ``device_idle`` reads nothing."""
-    import json
-    from test_chipbench_run import CELLS, SEED, TINY, load_bench
-    conf = json.loads(json.dumps(TINY["conf"]))
-    conf["pool"]["prefix_cache"] = False
+    from test_chipbench_run import CELLS, SEED, TINY, load_bench, tiny_conf
     res = cell.run_cell(load_bench(), CELLS["batch"], SEED, 1.0, True,
-                        conf=conf, mix=TINY["mixes"]["batch"],
+                        conf=tiny_conf("batch"), mix=TINY["mixes"]["batch"],
                         peaks=TINY["peaks"])
     assert res["correct"], res["check"]
     got = {k.split(".")[0]: v["value"] for k, v in res["metrics"].items()}

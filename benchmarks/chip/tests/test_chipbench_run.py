@@ -42,15 +42,21 @@ def limits(mix: str) -> dict:
     return cell.load_json(HERE / "limits" / f"{CELLS[mix]}.json")
 
 
-def tiny_run(mix: str, seed: int = SEED, bits=None, lims=None) -> dict:
-    """One run at the tiny size, with the prefix cache on or off as the
-    cell's own configuration has it."""
-    bench = load_bench()
-    c = next(w for w in bench["workloads"] if w["name"] == CELLS[mix])
+def tiny_conf(mix: str) -> dict:
+    """The tiny model, with the weights' ``init`` and the prefix cache on
+    or off as the cell's own configuration has them."""
+    c = next(w for w in load_bench()["workloads"] if w["name"] == CELLS[mix])
+    own = cell.load_json(HERE / "configs" / f"{c['config']}.json")
     conf = json.loads(json.dumps(TINY["conf"]))
-    conf["pool"]["prefix_cache"] = cell.load_json(
-        HERE / "configs" / f"{c['config']}.json")["pool"]["prefix_cache"]
-    return cell.run_cell(bench, CELLS[mix], seed, 1.0, False, conf=conf,
+    conf["init"] = own["init"]
+    conf["pool"]["prefix_cache"] = own["pool"]["prefix_cache"]
+    return conf
+
+
+def tiny_run(mix: str, seed: int = SEED, bits=None, lims=None) -> dict:
+    """One run of the cell's mix at the tiny size (``tiny_conf``)."""
+    return cell.run_cell(load_bench(), CELLS[mix], seed, 1.0, False,
+                         conf=tiny_conf(mix),
                          mix=TINY["mixes"][mix], limits=lims,
                          peaks=TINY["peaks"], bits=bits)
 
@@ -81,3 +87,20 @@ def test_control_is_not_correct(mix):
     assert not res["correct"], res["check"]
 
 
+@pytest.mark.parametrize("trace", [False, True])
+@pytest.mark.parametrize("mix", ["batch", "longchat"])
+def test_metric_names_are_those_listing_the_cell(mix, trace):
+    """A cell reports exactly the metrics of its group that list it or list
+    no cell: the chat cell no ``output_tok_s`` and none of the batch cell's
+    per-layer metrics."""
+    bench = load_bench()
+    group = bench["per_layer" if trace else "end_to_end"]
+    want = [m["name"] for m in group
+            if CELLS[mix] in m.get("workloads", [CELLS[mix]])]
+    got = cell.metric_names(bench, CELLS[mix], trace)
+    assert got == want and len(set(got)) == len(got)
+    assert all(m["name"].split(".")[-1] == mix for m in group
+               if m["name"] in got and "." in m["name"])
+    if not trace:
+        assert {"setup_s", "itl_p95_ms", "quantize_s"} <= set(got)
+        assert ("output_tok_s" in got) == (mix == "batch")

@@ -7,6 +7,7 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 from chipbench import tracefile as tf  # noqa: E402
+from chipbench.cell import Ctx, reader  # noqa: E402
 
 DEV, HOST = "/device:TPU:0", "/host:CPU"
 
@@ -93,6 +94,21 @@ def test_reduce_averages_over_chips():
 def test_reduce_without_device_events_reads_nothing():
     r = tf.reduce([ev("bench.window", 0, 10, HOST, "python")])
     assert r.busy_ns == 0 and r.kernel_ns == {} and r.window_ns == 10
+
+
+def test_prefill_chunk_ms_is_device_time_per_chunk():
+    """``jit__chunk`` executions in the window, the part of one that
+    outlives it clipped, over their count; a window without a chunk, or an
+    untraced run, reads nothing."""
+    events = synthetic() + [
+        ev("jit__chunk(7)", 520, 60, line=tf.MODULES_LINE),
+        ev("jit__chunk(7)", 960, 80, line=tf.MODULES_LINE),
+        ev("jit__chunk(7)", 1200, 90, line=tf.MODULES_LINE)]
+    read = reader("prefill_chunk_ms.longchat")
+    assert read(Ctx(trace=tf.reduce(events))) == pytest.approx(
+        (60 + 40) / 2 / 1e6)
+    assert read(Ctx(trace=tf.reduce(synthetic()))) is None
+    assert read(Ctx(trace=None)) is None
 
 
 def test_load_reads_host_spans_of_a_recorded_trace(tmp_path):

@@ -47,6 +47,27 @@ def test_seeds_share_the_sizes_in_another_order():
     assert np.median(la) == pytest.approx(128, abs=1)
 
 
+def test_sweep_gives_each_rate_turns_of_its_own():
+    """``sweep.py`` serves each rate fresh turns after the same histories,
+    with the same sizes and arrivals, so no rate finds an earlier rate's
+    turns in the prefix cache."""
+    import sweep
+    a, b = (sweep.fresh_traffic(CHAT, BIG, 1000, salt) for salt in (0, 1))
+    plain = Traffic(CHAT, BIG, 1000)
+    assert all(np.array_equal(x, y) for x, y in zip(a.histories,
+                                                   plain.histories))
+    for k in range(20):
+        ra, rb, rp = a.request(k), b.request(k), plain.request(k)
+        assert (ra.max_new, ra.due, ra.session) == (rb.max_new, rb.due,
+                                                    rb.session)
+        assert (ra.max_new, ra.due, ra.session) == (rp.max_new, rp.due,
+                                                    rp.session)
+        hist = len(plain.histories[rp.session])
+        assert np.array_equal(ra.prompt[:hist], rp.prompt[:hist])
+        assert len(ra.prompt) == len(rb.prompt) == len(rp.prompt)
+        assert not np.array_equal(ra.prompt[hist:], rb.prompt[hist:])
+
+
 def test_quantile_pools():
     u = quantile_pool({"dist": "uniform", "lo": 16, "hi": 96}, 810)
     assert u.min() == 16 and u.max() == 96

@@ -5,6 +5,21 @@ A cell of ``BENCHMARK.json`` names a configuration (``configs/<config>
 mix (``traffic/<traffic>.json``) and its limits (``limits/<cell>.json``).
 A metric is read by ``metrics/<name>.py``, or, for a name ``<stem>.<cell
 part>``, by ``metrics/<stem>.py``.  Adding any of these is adding a file.
+
+A configuration's ``model`` object is the program's ``ModelConfig``, key
+for key (``session.model_config``): any field of it, a nested object as
+the dataclass its field holds (``moe`` as ``MoEConfig``), a list as a
+tuple, ``family`` ``"dense"`` unless given.  A key that is no field stops
+the run.  A mixture-of-experts model is cut to one chip's share of an
+expert-parallel deployment (the ``model-configs`` guide, section 4):
+``model.moe.n_experts`` counts the experts held on this chip and is
+listed in ``reduced``; the router keeps its published width, which the
+harness reads from the router's weight (``(d, E_router)``), and its
+experts per token (``top_k``); the file states the published expert count
+and the deployment beside them.  Each held expert stack is counted as one
+grouped matrix of the fused kernel (``work.QMatrix.groups``, ``share``),
+and a token's operations count the held experts' expected share of its
+``top_k`` (``work.dense_token_flops``).
 """
 from __future__ import annotations
 
@@ -135,7 +150,10 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float,
     import jax
     t0 = time.perf_counter() if t0 is None else t0
     cell = find_cell(bench, name)
-    conf = conf or load_json(HERE / "configs" / f"{cell['config']}.json")
+    where = None                  # the configuration's file, for its errors
+    if conf is None:
+        where = str(HERE / "configs" / f"{cell['config']}.json")
+        conf = load_json(where)
     mix = mix or load_json(HERE / "traffic" / f"{cell['traffic']}.json")
     limits = limits or load_json(HERE / "limits" / f"{name}.json")
     dev = device.describe(jax.devices())
@@ -146,7 +164,7 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float,
     times: dict = {}
 
     with session.precision_scope(conf):
-        cfg = session.model_config(conf)
+        cfg = session.model_config(conf, where)
         ts = time.perf_counter()
         weights = ref.init_weights(model, seed, conf.get("init"))
         jax.block_until_ready(weights)
@@ -159,7 +177,8 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float,
             f" (asked {rep.requested_avg_bits}): calibrate "
             f"{times['calibrate_s']!r}s, calibrate+quantize "
             f"{times['quantize_s']!r}s, weights {times['weights_s']!r}s")
-        qmats = session.quantized_matrices(qparams)
+        qmats = session.quantized_matrices(qparams, cfg)
+        experts = session.experts(qparams)
         log(f"device bytes in use with the quantized model: {in_use()}")
         ts = time.perf_counter()
         engine = session.build_engine(conf, cfg, qparams)
@@ -195,7 +214,9 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float,
         win.run(seconds, on_open=on_open)
         if trace:
             marks["ann"].__exit__(None, None, None)
+            ts = time.perf_counter()
             jax.profiler.stop_trace()
+            log(f"trace: stop_trace {time.perf_counter() - ts!r}s")
     times["setup_s"] = marks["open"] - t0
     times["ramp_s"] = marks["open"] - t0 - sum(
         times[k] for k in ("weights_s", "quantize_s", "warm_up_s",
@@ -217,17 +238,16 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float,
         f"{al.num_blocks - 1 - al.free_blocks} held by requests, "
         f"{al.cached_idle_blocks} cached idle, of {al.num_blocks - 1}")
 
-    red = None
+    red = events = None
     if trace:
         ts = time.perf_counter()
         events = tracefile.load(tdir)
+        tr = time.perf_counter()
         red = tracefile.reduce(events)
-        (OUT / "trace_summary.json").write_text(json.dumps(
-            tracefile.summary(events), indent=1, default=str))
         dev["busy_s"] = red.busy_ns / 1e9
         dev["window_s"] = red.window_ns / 1e9
-        log(f"trace: {len(events)} events read and reduced in "
-            f"{time.perf_counter() - ts!r}s; kernels {red.kernel_ns}, "
+        log(f"trace: {len(events)} events read in {tr - ts!r}s and reduced"
+            f" in {time.perf_counter() - tr!r}s; kernels {red.kernel_ns}, "
             f"modules {red.module_ns}")
     late = [r.submitted - r.due for r in win.recs.values()
             if win.start <= r.due < win.end]
@@ -237,17 +257,25 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float,
 
     ctx = Ctx(conf=conf, model=model, mix=mix, win=win, steps=win.steps,
               stats=stats_final, times=times, trace=red, peaks=peaks,
-              qmatrices=qmats, slots=engine.pool.max_slots,
+              events=events, qmatrices=qmats, experts=experts,
+              slots=engine.pool.max_slots,
               block_size=engine.pool.block_size,
               kv_bytes=2 if conf["precision"]["kv"] == "bf16" else 4)
     unit = units(bench)
     metrics = {}
+    ts = time.perf_counter()
     for mname in metric_names(bench, name, trace):
         v = reader(mname)(ctx)
         if v is not None and math.isfinite(v):
             metrics[mname] = {"value": float(v), "unit": unit[mname]}
     for k, v in ctx.notes:
         log(f"{k}: {v}")
+    if trace:
+        tr = time.perf_counter()
+        (OUT / "trace_summary.json").write_text(json.dumps(
+            tracefile.summary(events), indent=1, default=str))
+        log(f"trace: metrics read in {tr - ts!r}s, summary written in "
+            f"{time.perf_counter() - tr!r}s")
 
     # the output check, with the program's state freed first
     due = [r for r in win.recs.values() if win.start <= r.due < win.end]

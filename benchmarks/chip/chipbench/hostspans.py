@@ -20,37 +20,11 @@ A program without these spans yields nothing: every share is ``None``.
 """
 from __future__ import annotations
 
-import glob
-from pathlib import Path
+from .tracefile import OPS_LINE, device_planes, gaps
 
-from .cell import OUT
-from .tracefile import DEVICE_PLANE, OPS_LINE, Event, gaps
-
-TRACE = OUT / "trace"            # where ``cell.run_cell`` records the window
 PREFIX = "serve."
 WINDOW = "bench.window"
 PARTS = ("pull", "sample", "dispatch", "outside")
-
-
-def load(trace_dir: str | Path = TRACE) -> list[Event]:
-    """The device operations, the window and the ``serve.*`` spans of the
-    newest ``.xplane.pb`` under ``trace_dir``."""
-    from jax.profiler import ProfileData
-    files = sorted(glob.glob(str(Path(trace_dir) / "**" / "*.xplane.pb"),
-                             recursive=True))
-    if not files:
-        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    out = []
-    for plane in ProfileData.from_file(files[-1]).planes:
-        device = bool(DEVICE_PLANE.match(plane.name))
-        for line in plane.lines:
-            if device and line.name != OPS_LINE:
-                continue
-            for e in line.events:
-                if device or e.name == WINDOW or e.name.startswith(PREFIX):
-                    out.append(Event(plane.name, line.name, e.name,
-                                     float(e.start_ns), float(e.duration_ns)))
-    return out
 
 
 def part_of(span: str | None) -> str:
@@ -108,27 +82,32 @@ def charge(idle, segs) -> dict:
     return out
 
 
-def split(events: list[Event]) -> dict | None:
+def split(events: list) -> dict | None:
     """Each part's share of the window (%), idle time averaged over the
     chips as ``tracefile.reduce`` averages busy time, and the idle
     milliseconds under each innermost span; ``None`` without a window,
-    device operations or ``serve.*`` spans."""
-    host = [e for e in events if not DEVICE_PLANE.match(e.plane)]
+    device operations or ``serve.*`` spans.  ``events`` are
+    ``tracefile.load``'s: the device's operations are those on its
+    ``XLA Ops`` line."""
+    device = device_planes(events)
+    host = [e for e in events if e.plane not in device]
     win = [e for e in host if e.name == WINDOW]
     spans = [(e.start_ns, e.end_ns, e.name[len(PREFIX):]) for e in host
              if e.name.startswith(PREFIX)]
-    dev = [e for e in events if DEVICE_PLANE.match(e.plane)]
+    dev = [e for e in events if e.plane in device and e.line == OPS_LINE]
     if not win or not spans or not dev:
         return None
     lo = min(e.start_ns for e in win)
     hi = max(e.end_ns for e in win)
     segs = segments(spans, lo, hi)
     chips = sorted({e.plane for e in dev})
+    ivs: dict = {c: [] for c in chips}
+    for e in dev:
+        if e.end_ns > lo and e.start_ns < hi:
+            ivs[e.plane].append((max(e.start_ns, lo), min(e.end_ns, hi)))
     by_span: dict = {}
     for chip in chips:
-        iv = [(max(e.start_ns, lo), min(e.end_ns, hi)) for e in dev
-              if e.plane == chip and e.end_ns > lo and e.start_ns < hi]
-        for name, ns in charge(gaps(iv, lo, hi), segs).items():
+        for name, ns in charge(gaps(ivs[chip], lo, hi), segs).items():
             by_span[name] = by_span.get(name, 0.0) + ns
     scale = len(chips) * (hi - lo)
     share = dict.fromkeys(PARTS, 0.0)
@@ -140,16 +119,14 @@ def split(events: list[Event]) -> dict | None:
                                                key=lambda x: -x[1])}}
 
 
-def idle_share(ctx, part: str, trace_dir: str | Path = TRACE):
+def idle_share(ctx, part: str):
     """Share of the traced window (%) in which the device idled under
-    ``part``; read once per run and kept on ``ctx``."""
+    ``part``, from the run's events (``ctx.events``); split once per run
+    and kept on ``ctx``."""
     if ctx.trace is None:
         return None
     if not hasattr(ctx, "serve_idle"):
-        try:
-            ctx.serve_idle = split(load(trace_dir))
-        except FileNotFoundError:
-            ctx.serve_idle = None
+        ctx.serve_idle = split(ctx.events)
         if ctx.serve_idle is not None:
             ctx.note("device idle under serve spans (ms)",
                      ctx.serve_idle["span_ms"])

@@ -27,15 +27,46 @@ def load_reference(name: str):
     return mod
 
 
-def model_config(conf: dict):
+def model_config(conf: dict, where: str | None = None):
+    """The program's ``ModelConfig`` from ``conf["model"]``: every key a
+    field of it, a nested object the dataclass its field holds (``moe``,
+    ``mla``), a list a tuple.  ``family`` is ``"dense"`` unless given, and
+    ``name`` is the configuration's.  A key that is no field raises,
+    naming it and ``where`` (the file), so a typo never builds another
+    model."""
     from repro.models.config import ModelConfig
-    m = conf["model"]
-    return ModelConfig(name=conf["name"], family="dense",
-                       n_layers=m["n_layers"], d_model=m["d_model"],
-                       n_heads=m["n_heads"], n_kv=m["n_kv"], d_ff=m["d_ff"],
-                       vocab=m["vocab"], head_dim=m["head_dim"],
-                       rope_theta=m["rope_theta"],
-                       qk_norm=bool(m.get("qk_norm", False)))
+    where = where or f"configuration {conf.get('name')!r}"
+    return _build(ModelConfig, {"name": conf["name"], "family": "dense",
+                                **conf["model"]}, where, "model")
+
+
+def _build(cls, values: dict, where: str, path: str):
+    import typing
+    hints = typing.get_type_hints(cls)
+    names = {f.name for f in dataclasses.fields(cls)}
+    kw = {}
+    for key, v in values.items():
+        if key not in names:
+            raise ValueError(f"{where}: {path}.{key} is not a field of "
+                             f"{cls.__name__} (fields: {sorted(names)})")
+        kw[key] = _value(hints[key], v, where, f"{path}.{key}")
+    return cls(**kw)
+
+
+def _value(hint, v, where: str, path: str):
+    """``v`` as field type ``hint`` holds it: an object as the dataclass
+    among ``hint``'s types, a list as a tuple."""
+    import typing
+    if isinstance(v, dict):
+        kinds = [t for t in typing.get_args(hint) or (hint,)
+                 if dataclasses.is_dataclass(t)]
+        if not kinds:
+            raise ValueError(f"{where}: {path} is an object, but the field "
+                             f"holds {hint}")
+        return _build(kinds[0], v, where, path)
+    if isinstance(v, list):
+        return tuple(_value(typing.Any, x, where, path) for x in v)
+    return v
 
 
 def pool_config(conf: dict):
@@ -97,17 +128,61 @@ def quantize(conf: dict, cfg, weights: dict, seed: int, bits=None):
     return qparams, rep, calibrate_s, time.perf_counter() - t0
 
 
-def quantized_matrices(qparams) -> list:
-    """Every quantized linear's shape as the fused kernel sees it."""
-    import jax
-    from repro.core.qlinear import QuantizedLinear
+def quantized_matrices(qparams, cfg) -> list:
+    """Every quantized matrix's shape as the fused kernel sees it: each
+    ``QuantizedLinear``, and each ``QuantizedGrouped`` (an MoE layer's
+    stacked experts) as one ``QMatrix`` of ``groups`` experts held that
+    takes ``share = top_k * groups / E_router`` of a token's rows, with
+    ``E_router`` the outputs of the same layer's router."""
+    from repro.core.qlinear import QuantizedGrouped, QuantizedLinear
 
     from .work import QMatrix
-    is_q = lambda x: isinstance(x, QuantizedLinear)   # noqa: E731
-    return [QMatrix(d_keep=q.d_keep, c=q.c, bits=q.bits,
-                    d_hat=int(q.signs1.shape[0]),
-                    two_blocks=q.signs2 is not None)
-            for q in jax.tree.leaves(qparams, is_leaf=is_q) if is_q(q)]
+    out = []
+    for path, q in _quantized(qparams):
+        two = q.signs2 is not None
+        if isinstance(q, QuantizedLinear):
+            out.append(QMatrix(d_keep=q.d_keep, c=q.c, bits=q.bits,
+                               d_hat=int(q.signs1.shape[0]), two_blocks=two))
+        elif isinstance(q, QuantizedGrouped):
+            held = int(q.packed.shape[0])
+            share = cfg.moe.top_k * held / _router(qparams, path)
+            out.append(QMatrix(d_keep=q.d, c=q.c, bits=q.bits,
+                               d_hat=int(q.signs1.shape[0]), two_blocks=two,
+                               groups=held, share=share))
+    return out
+
+
+def experts(qparams) -> list:
+    """One ``work.Experts`` per MoE layer, in the order of the params:
+    its router's outputs and the experts its ``wi`` holds."""
+    from repro.core.qlinear import QuantizedGrouped
+
+    from .work import Experts
+    return [Experts(router=_router(qparams, path), held=int(q.packed.shape[0]))
+            for path, q in _quantized(qparams)
+            if isinstance(q, QuantizedGrouped) and path[-1].key == "wi"]
+
+
+def _quantized(qparams) -> list:
+    """``(path, leaf)`` of every quantized matrix, in the params' order."""
+    import jax
+    from repro.core.qlinear import QuantizedGrouped, QuantizedLinear
+    is_q = lambda x: isinstance(x, (QuantizedLinear,   # noqa: E731
+                                    QuantizedGrouped))
+    return [(p, q) for p, q in jax.tree_util.tree_flatten_with_path(
+        qparams, is_leaf=is_q)[0] if is_q(q)]
+
+
+def _router(qparams, path) -> int:
+    """The outputs of the router beside the grouped matrix at ``path``."""
+    node = qparams
+    for k in path[:-1]:
+        node = node[k.key if hasattr(k, "key") else k.idx]
+    if "router" not in node:
+        import jax
+        raise KeyError("no router beside the expert matrix at "
+                       + jax.tree_util.keystr(path))
+    return int(node["router"].shape[-1])
 
 
 def build_engine(conf: dict, cfg, qparams):

@@ -1,14 +1,15 @@
 """Reduce a profiler trace to device busy time, per-kernel and per-module
 device time, and idle gaps labelled by the harness's host spans.
 
-A trace is read into plain ``Event`` records (``load``), so the reduction
-itself (``reduce``) runs on any list of events, recorded or made up.
-Device events are those on planes named ``/device:TPU:<n>``; on those
-planes the ``XLA Ops`` line holds one event per executed operation (a
-Pallas kernel is one operation named after its ``pallas_call``), and the
-``XLA Modules`` line one event per executed jitted program (``jit__step``,
-``jit__chunk``).  Host spans are ``TraceAnnotation`` events whose name
-starts with ``bench.`` on the host plane.
+A trace is read once into plain ``Event`` records (``load``), so the
+reductions (``reduce`` here, ``hostspans.split``) run on any list of
+events, recorded or made up.  Device events are those on planes named
+``/device:TPU:<n>``; on those planes the ``XLA Ops`` line holds one event
+per executed operation (a Pallas kernel is one operation named after its
+``pallas_call``), and the ``XLA Modules`` line one event per executed
+jitted program (``jit__step``, ``jit__chunk``).  Host spans are
+``TraceAnnotation`` events on the host plane: the harness's, whose names
+start with ``bench.``, and the serving engine's, with ``serve.``.
 """
 from __future__ import annotations
 
@@ -17,15 +18,16 @@ import dataclasses
 import glob
 import re
 from pathlib import Path
+from typing import NamedTuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench."
+HOST_PREFIXES = (SPAN_PREFIX, "serve.")
 
 
-@dataclasses.dataclass(frozen=True)
-class Event:
+class Event(NamedTuple):
     plane: str
     line: str
     name: str
@@ -39,7 +41,9 @@ class Event:
 
 
 def load(trace_dir: str | Path) -> list[Event]:
-    """Every event of the newest ``.xplane.pb`` under ``trace_dir``."""
+    """Every device event and every ``bench.*`` and ``serve.*`` host span
+    of the newest ``.xplane.pb`` under ``trace_dir``, in one pass; each
+    op's framework name is looked up once per distinct name."""
     from jax.profiler import ProfileData
     files = sorted(glob.glob(str(Path(trace_dir) / "**" / "*.xplane.pb"),
                              recursive=True))
@@ -47,22 +51,32 @@ def load(trace_dir: str | Path) -> list[Event]:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     out = []
     for plane in ProfileData.from_file(files[-1]).planes:
-        keep_all = bool(DEVICE_PLANE.match(plane.name))
+        pname = plane.name
+        device = bool(DEVICE_PLANE.match(pname))
         for line in plane.lines:
+            lname = line.name
+            ops = device and lname == OPS_LINE
             sources: dict[str, str] = {}    # op name -> its tf_op, read once
             for e in line.events:
-                if keep_all or e.name.startswith(SPAN_PREFIX):
-                    src = ""
-                    if keep_all and line.name == OPS_LINE:
-                        if e.name not in sources:
-                            sources[e.name] = next(
-                                (str(v) for k, v in e.stats
-                                 if k in ("tf_op", "long_name")), "")
-                        src = sources[e.name]
-                    out.append(Event(plane.name, line.name, e.name,
-                                     float(e.start_ns), float(e.duration_ns),
-                                     src))
+                name = e.name
+                if not device and not name.startswith(HOST_PREFIXES):
+                    continue
+                src = ""
+                if ops:
+                    src = sources.get(name)
+                    if src is None:
+                        src = sources[name] = next(
+                            (str(v) for k, v in e.stats
+                             if k in ("tf_op", "long_name")), "")
+                out.append(Event(pname, lname, name, float(e.start_ns),
+                                 float(e.duration_ns), src))
     return out
+
+
+def device_planes(events) -> set:
+    """The names of the device planes among ``events``' planes, each
+    matched once."""
+    return {p for p in {e.plane for e in events} if DEVICE_PLANE.match(p)}
 
 
 def union_ns(intervals) -> float:
@@ -144,9 +158,12 @@ class Reduced:
 def reduce(events: list[Event], kernels=("rht_qmatmul", "paged_attention"),
            window_span: str = "bench.window", top: int = 10) -> Reduced:
     """Reduce ``events`` over the host span ``window_span`` (or, without
-    one, the extent of the device events)."""
-    spans = [e for e in events if not DEVICE_PLANE.match(e.plane)]
-    dev = [e for e in events if DEVICE_PLANE.match(e.plane)]
+    one, the extent of the device events).  Idle gaps are labelled by the
+    harness's spans (``bench.*``) alone.  An op's kind and kernel are
+    worked out once per distinct name."""
+    device = device_planes(events)
+    spans = [e for e in events if e.plane not in device]
+    dev = [e for e in events if e.plane in device]
     win = [e for e in spans if e.name == window_span]
     if win:
         lo = min(e.start_ns for e in win)
@@ -160,37 +177,46 @@ def reduce(events: list[Event], kernels=("rht_qmatmul", "paged_attention"),
         return Reduced(hi - lo, 0.0, 0, {}, {}, {}, {}, [], [])
     chips = sorted({e.plane for e in dev})
     n = max(len(chips), 1)
+    ops_of = {c: [] for c in chips}
+    mods_of = {c: [] for c in chips}
+    for e in dev:
+        if e.end_ns > lo and e.start_ns < hi:
+            if e.line == OPS_LINE:
+                ops_of[e.plane].append(e)
+            elif e.line == MODULES_LINE:
+                mods_of[e.plane].append(e)
     busy, k_ns, k_calls, m_ns, m_calls, op_ns = 0.0, {}, {}, {}, {}, {}
+    kinds: dict = {}              # (name, source) -> (op kind, kernel)
     idle = []
-    inner = sorted((e for e in spans if e.name != window_span
+    inner = sorted((e for e in spans if e.name.startswith(SPAN_PREFIX)
+                    and e.name != window_span
                     and e.end_ns > lo and e.start_ns < hi),
                    key=lambda e: e.start_ns)
+    starts = [e.start_ns for e in inner]
     for chip in chips:
-        ops = [e for e in dev if e.plane == chip and e.line == OPS_LINE
-               and e.end_ns > lo and e.start_ns < hi]
+        ops = ops_of[chip]
         iv = [clip(e, lo, hi) for e in ops]
         busy += union_ns(iv)
-        for e in ops:
-            s, t = clip(e, lo, hi)
-            op = op_kind(e.name)
+        for e, (s, t) in zip(ops, iv):
+            key = (e.name, e.source)
+            kk = kinds.get(key)
+            if kk is None:
+                kk = kinds[key] = (op_kind(e.name),
+                                   kernel_of(e.name, kernels, e.source))
+            op, k = kk
             op_ns[op] = op_ns.get(op, 0.0) + (t - s)
-            k = kernel_of(e.name, kernels, e.source)
             if k:
                 k_ns[k] = k_ns.get(k, 0.0) + (t - s)
                 k_calls[k] = k_calls.get(k, 0) + 1
-        for e in dev:
-            if (e.plane == chip and e.line == MODULES_LINE
-                    and e.end_ns > lo and e.start_ns < hi):
-                s, t = clip(e, lo, hi)
-                m = module_of(e.name)
-                m_ns[m] = m_ns.get(m, 0.0) + (t - s)
-                m_calls[m] = m_calls.get(m, 0) + 1
+        for e in mods_of[chip]:
+            s, t = clip(e, lo, hi)
+            m = module_of(e.name)
+            m_ns[m] = m_ns.get(m, 0.0) + (t - s)
+            m_calls[m] = m_calls.get(m, 0) + 1
         for g0, g1 in gaps(iv, lo, hi):
-            idle.append((span_at(inner, (g0 + g1) / 2), (g1 - g0) / n))
+            idle.append((span_at(inner, starts, (g0 + g1) / 2),
+                         (g1 - g0) / n))
     scale = lambda d: {k: v / n for k, v in d.items()}   # noqa: E731
-    by_label: dict[str, float] = {}
-    for label, s in idle:
-        by_label[label] = by_label.get(label, 0.0) + s
     longest = sorted(idle, key=lambda x: -x[1])[:top]
     return Reduced(
         window_ns=hi - lo, busy_ns=busy / n, chips=n,
@@ -201,12 +227,12 @@ def reduce(events: list[Event], kernels=("rht_qmatmul", "paged_attention"),
         idle_gaps=[(lab, s / 1e9) for lab, s in longest])
 
 
-def span_at(spans, t: float) -> str:
+def span_at(spans, starts, t: float) -> str:
     """The host span covering time ``t`` that started last (the harness's
     spans inside the window follow one another), by name without the
     ``bench.`` prefix; ``"none"`` where no span covers it.  ``spans`` is
-    sorted by start."""
-    i = bisect.bisect_right([e.start_ns for e in spans], t) - 1
+    sorted by start, and ``starts`` holds their starts."""
+    i = bisect.bisect_right(starts, t) - 1
     while i >= 0:
         if spans[i].end_ns >= t:
             return spans[i].name[len(SPAN_PREFIX):]

@@ -35,21 +35,29 @@ class Work:
 
 @dataclasses.dataclass(frozen=True)
 class QMatrix:
-    """One quantized linear as the fused RHT+qmatmul kernel sees it."""
+    """One quantized matrix as the fused RHT+qmatmul kernel sees it: a
+    linear, or an MoE layer's stacked experts, one call of the kernel for
+    each expert held."""
     d_keep: int      # input rows after outlier rows are split off
     c: int           # output columns
     bits: int        # allocated bits per weight
     d_hat: int       # Hadamard block (largest power of two <= d_keep)
     two_blocks: bool  # a second, overlapped Hadamard block (Alg. 5)
+    groups: int = 1   # experts held, each with its own codes and rescale
+    share: float = 1  # expected (token, held expert) pairs per token
 
 
 def rht_qmatmul(n: int, q: QMatrix) -> Work:
-    """One call on ``n`` activation rows: f32 activations in and out, packed
-    codes, f32 rescale and sign vectors."""
+    """The calls on ``n`` tokens' activation rows: ``n * share`` rows in
+    all, f32 activations in and out, each group's packed codes and f32
+    rescale, and the f32 sign vectors, which the groups share."""
+    rows = n * q.share
     codes = math.ceil(q.d_keep * q.bits / 8) * q.c
-    side = 4 * q.c + 4 * q.d_hat * (2 if q.two_blocks else 1)
-    io = 4 * n * (q.d_keep + q.c)
-    return Work(2.0 * n * q.d_keep * q.c, codes + side + io)
+    side = 4 * q.c
+    signs = 4 * q.d_hat * (2 if q.two_blocks else 1)
+    io = 4 * rows * (q.d_keep + q.c)
+    return Work(2.0 * rows * q.d_keep * q.c,
+                q.groups * (codes + side) + signs + io)
 
 
 def paged_attention(contexts, n_heads: int, n_kv: int, head_dim: int,
@@ -66,18 +74,34 @@ def paged_attention(contexts, n_heads: int, n_kv: int, head_dim: int,
     return Work(flops, byts)
 
 
-def dense_token_flops(m: dict) -> float:
+@dataclasses.dataclass(frozen=True)
+class Experts:
+    """One MoE layer's experts as its quantized params hold them."""
+    router: int      # the router's outputs: the experts it routes over
+    held: int        # the experts held here
+
+
+def dense_token_flops(m: dict, experts=()) -> float:
     """Dense-equivalent operations of one token through every linear and
-    the ``lm_head`` (attention over the context excluded)."""
+    the ``lm_head`` (attention over the context excluded).  Each layer of
+    ``experts`` (``Experts``, one per MoE layer) counts its router, its
+    held experts' expected share of the token's ``top_k`` and its shared
+    experts in place of the dense FFN of ``d_ff``."""
     d, h, kv, hd, f = (m["d_model"], m["n_heads"], m["n_kv"], m["head_dim"],
                        m["d_ff"])
-    per_layer = d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f
-    return 2.0 * (m["n_layers"] * per_layer + d * m["vocab"])
+    attn = d * h * hd + 2 * d * kv * hd + h * hd * d
+    ffn = (m["n_layers"] - len(experts)) * 3 * d * f
+    for e in experts:
+        moe = m["moe"]
+        expert = 3 * d * moe["d_ff_expert"]
+        ffn += (d * e.router + expert * moe["top_k"] * e.held / e.router
+                + expert * moe.get("n_shared", 0))
+    return 2.0 * (m["n_layers"] * attn + ffn + d * m["vocab"])
 
 
-def decode_step_flops(m: dict, contexts) -> float:
+def decode_step_flops(m: dict, contexts, experts=()) -> float:
     """Useful operations of one decode step: each active slot's token
     through the linears and the ``lm_head``, and attention over its live
     context in every layer."""
     att = 4.0 * m["n_heads"] * m["head_dim"] * m["n_layers"]
-    return sum(dense_token_flops(m) + att * t for t in contexts)
+    return sum(dense_token_flops(m, experts) + att * t for t in contexts)

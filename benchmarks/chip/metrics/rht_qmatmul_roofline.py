@@ -3,7 +3,8 @@ window: the least time the chip could take for every call (the larger of
 operations over the bf16 peak and bytes over HBM bandwidth, from
 ``work.rht_qmatmul`` at each matrix's allocated bits) over the summed
 device time of the ``rht_qmatmul`` events.  Decode calls run on every
-slot's row; a prefill chunk's on its tokens."""
+slot's row; a prefill chunk's on its tokens; an MoE layer's held experts
+on their expected share of those rows (``QMatrix.share``)."""
 from chipbench import work
 
 

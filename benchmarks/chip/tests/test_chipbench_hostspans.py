@@ -107,12 +107,16 @@ def ctx(stats0=None, stats1=None, trace=None):
 
 
 @pytest.mark.parametrize("name", IDLE)
-def test_idle_readers(name, tmp_path):
+def test_idle_readers(name):
     part = name.split("_")[1]
     read = cell.reader(f"{name}.batch")
     assert read(ctx()) is None                      # untraced
     c = ctx(trace=tf.reduce(iteration()))
-    assert hs.idle_share(c, part, tmp_path) is None   # no trace file
+    c.events = [e for e in iteration() if not e.name.startswith("serve.")]
+    assert hs.idle_share(c, part) is None           # no serve spans
+    c = ctx(trace=tf.reduce(iteration()))
+    c.events = iteration()
+    assert read(c) == pytest.approx(shares(iteration())[part])
     c = ctx(trace=tf.reduce(iteration()))
     c.serve_idle = hs.split(iteration())
     assert read(c) == pytest.approx(shares(iteration())[part])
@@ -140,6 +144,8 @@ def test_counter_readers():
 
 
 def test_load_reads_serve_spans_of_a_recorded_trace(tmp_path):
+    """The one read of a trace (``tracefile.load``) keeps the engine's
+    spans beside the harness's."""
     import jax
     import jax.numpy as jnp
     from repro.serve.tracing import span
@@ -153,7 +159,7 @@ def test_load_reads_serve_spans_of_a_recorded_trace(tmp_path):
             with span(stats, "decode", slots=3):
                 f(x).block_until_ready()
     jax.profiler.stop_trace()
-    events = hs.load(tmp_path)
+    events = tf.load(tmp_path)
     names = sorted(e.name for e in events
                    if not tf.DEVICE_PLANE.match(e.plane))
     assert names == ["bench.window", "serve.decode", "serve.step"]

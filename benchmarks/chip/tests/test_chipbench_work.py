@@ -49,6 +49,85 @@ def test_decode_step_flops():
     assert work.decode_step_flops(QWEN, []) == 0
 
 
+def committed(name: str) -> dict:
+    return json.loads((Path(__file__).resolve().parents[1] / "configs"
+                       / f"{name}.json").read_text())["model"]
+
+
+@pytest.mark.parametrize("name,flops", [
+    # 2 (24 (12,582,912 attention + 50,331,648 FFN) + 189,530,112 lm_head)
+    ("internlm2-1.8b", 3_398_959_104),
+    # 2 (28 (6,291,456 + 9,437,184) + 155,582,464)
+    ("qwen3-0.6b", 1_191_968_768)])
+def test_dense_token_flops_of_the_committed_models(name, flops):
+    m = committed(name)
+    assert work.dense_token_flops(m) == flops
+    assert work.dense_token_flops(m, ()) == flops
+    att = 4 * m["n_heads"] * m["head_dim"] * m["n_layers"]
+    assert work.decode_step_flops(m, [100, 900]) == 2 * flops + att * 1000
+
+
+def dense_rht_qmatmul(n, q):
+    """The count before grouped matrices: one linear."""
+    codes = -(-q.d_keep * q.bits // 8) * q.c
+    side = 4 * q.c + 4 * q.d_hat * (2 if q.two_blocks else 1)
+    return work.Work(2.0 * n * q.d_keep * q.c,
+                     codes + side + 4 * n * (q.d_keep + q.c))
+
+
+@pytest.mark.parametrize("shape", [(2042, 6144, 3, 1024, True),
+                                   (2048, 2048, 4, 2048, False),
+                                   (8168, 2048, 2, 4096, True),
+                                   (1021, 3072, 5, 512, True)])
+@pytest.mark.parametrize("n", [1, 32, 64])
+def test_a_linear_counts_as_before(shape, n):
+    q = work.QMatrix(*shape)
+    assert q.groups == 1 and q.share == 1
+    assert work.rht_qmatmul(n, q) == dense_rht_qmatmul(n, q)
+
+
+def test_grouped_rht_qmatmul_counts():
+    # an MoE layer's wi at Mellum2's widths: 8 of the router's 64 experts
+    # held, top 8, so one expected (token, held expert) pair a token;
+    # d 2304 (Hadamard blocks 2048 and 2048), c = 2 * 896, 3 bits
+    q = work.QMatrix(d_keep=2304, c=1792, bits=3, d_hat=2048,
+                     two_blocks=True, groups=8, share=8 * 8 / 64)
+    w = work.rht_qmatmul(64, q)
+    assert w.flops == 2 * 64 * 2304 * 1792
+    codes = 864 * 1792                     # 2304 * 3 / 8 bytes a column
+    assert w.bytes == (8 * (codes + 4 * 1792) + 4 * 2048 * 2
+                       + 4 * 64 * (2304 + 1792))
+    # top 2 of 64 with 8 held: a quarter of a row per token
+    q = work.QMatrix(d_keep=896, c=2304, bits=4, d_hat=512, two_blocks=True,
+                     groups=8, share=2 * 8 / 64)
+    w = work.rht_qmatmul(16, q)
+    assert w.flops == 2 * 4 * 896 * 2304
+    assert w.bytes == (8 * (448 * 2304 + 4 * 2304) + 4 * 512 * 2
+                       + 4 * 4 * (896 + 2304))
+
+
+MELLUM2 = {"n_layers": 28, "d_model": 2304, "n_heads": 32, "n_kv": 4,
+           "head_dim": 128, "d_ff": 7168, "vocab": 98304,
+           "moe": {"n_experts": 8, "top_k": 8, "d_ff_expert": 896}}
+
+
+def test_moe_token_flops():
+    # 28 (42,467,328 attention linears + 294,912 router + 12,386,304 for
+    # the one expected expert of the 8 held) + 452,984,832 lm_head
+    held = [work.Experts(router=64, held=8)] * 28
+    assert work.dense_token_flops(MELLUM2, held) == 1_997_144_064
+    # with two shared experts, each counted whole for every token
+    m = dict(MELLUM2, moe=dict(MELLUM2["moe"], n_shared=2))
+    assert work.dense_token_flops(m, held) == \
+        1_997_144_064 + 28 * 2 * 2 * 3 * 2304 * 896
+    # a layer without experts keeps the dense FFN of d_ff
+    assert work.dense_token_flops(MELLUM2, held[:27]) == \
+        1_997_144_064 - 294_912 - 12_386_304 + 2 * 3 * 2304 * 7168
+    att = 4 * 32 * 128 * 28
+    assert work.decode_step_flops(MELLUM2, [5, 7], held) == \
+        2 * 1_997_144_064 + att * 12
+
+
 def test_peaks_table_by_device_kind(tmp_path):
     v5e = device.peaks("TPU v5 lite")
     assert v5e["bf16_flops"] == 197e12 and v5e["hbm_bytes_per_s"] == 819e9
@@ -93,6 +172,20 @@ def test_roofline_reader_arithmetic():
     assert reader("rht_qmatmul_roofline.batch")(ctx) is None
 
 
+def test_roofline_reader_counts_grouped_matrices():
+    lin = work.QMatrix(d_keep=256, c=512, bits=4, d_hat=256,
+                       two_blocks=False)
+    grp = work.QMatrix(d_keep=256, c=256, bits=3, d_hat=256,
+                       two_blocks=False, groups=4, share=0.5)
+    steps = [StepRec(end=1.0, chunk=8, contexts=[5, 6])]
+    total = (work.rht_qmatmul(4, lin) + work.rht_qmatmul(8, lin)
+             + work.rht_qmatmul(4, grp) + work.rht_qmatmul(8, grp))
+    ideal = total.seconds(PEAKS["bf16_flops"], PEAKS["hbm_bytes_per_s"])
+    ctx = Ctx(trace=trace({"rht_qmatmul": 4 * ideal * 1e9}), steps=steps,
+              qmatrices=[lin, grp], slots=4, peaks=PEAKS)
+    assert reader("rht_qmatmul_roofline.batch")(ctx) == pytest.approx(25.0)
+
+
 def test_paged_attention_reader_and_mfu():
     m = dict(QWEN, n_layers=2)
     steps = [StepRec(end=1.0, chunk=0, contexts=[16, 40])]
@@ -100,7 +193,8 @@ def test_paged_attention_reader_and_mfu():
     ideal = 2 * one.bytes / 1e9
     ctx = Ctx(trace=trace({"paged_attention": 4 * ideal * 1e9},
                           {"jit__step": 1e9}, {"jit__step": 1}),
-              steps=steps, model=m, block_size=16, kv_bytes=2, peaks=PEAKS)
+              steps=steps, model=m, block_size=16, kv_bytes=2, peaks=PEAKS,
+              experts=())
     assert reader("paged_attention_roofline.longchat")(ctx) == \
         pytest.approx(25.0)
     flops = work.decode_step_flops(m, [16, 40])
